@@ -32,6 +32,10 @@ def build(size: str, mod: bool, capacity: float = 0.125, every: int = 2) -> Mode
         attn=AttentionConfig(n_heads=H, n_kv_heads=H, head_dim=D // H),
         mod=MoDConfig(enabled=mod, capacity_ratio=capacity, every=every),
         dtype="bfloat16",
+        # at the paper's 2048-token sequences the activations, not the
+        # weights, fill the device: recompute each layer group in the
+        # backward pass
+        remat="full",
     )
 
 

@@ -60,7 +60,6 @@ from typing import Callable, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.config import ModelConfig
@@ -150,12 +149,12 @@ def decide_tokens(
         # (manual-subgroup) region — and the decision is a per-row scalar op,
         # so replicating it across the model axis costs nothing.
         dspec = spmd.data_spec(2)
-        idx, gate, mask, logits = shard_map(
+        idx, gate, mask, logits = jax.shard_map(
             _local,
             mesh=spmd.mesh,
             in_specs=(jax.tree.map(lambda _: P(), params["router"]), spmd.data_spec(3)),
             out_specs=(dspec, dspec, dspec, dspec),
-            check_rep=False,
+            check_vma=False,
         )(params["router"], x)
         return RouteDecision("token_topk", idx, gate, mask, logits)
     logits = R.router_logits(params["router"], x)  # (B, S) f32
@@ -429,12 +428,12 @@ def spmd_gather_tokens(
     tokens inside ``shard_map`` — the (B, S, D) stream is never resharded.
     The region is fully manual (dispatch touches no model-sharded operand:
     the stream's D dim is replicated over the model axis)."""
-    return shard_map(
+    return jax.shard_map(
         lambda xl, il: _gather_tokens(xl, il, backend),
         mesh=spmd.mesh,
         in_specs=(spmd.data_spec(3), spmd.data_spec(2)),
         out_specs=spmd.data_spec(3),
-        check_rep=False,
+        check_vma=False,
     )(x, idx)
 
 
@@ -447,7 +446,7 @@ def spmd_scatter_add_tokens(
     backend: str,
 ) -> jax.Array:
     """Per-shard gated scatter-add (Eq. 1 combine) inside ``shard_map``."""
-    return shard_map(
+    return jax.shard_map(
         lambda xl, il, dl, gl: _scatter_add_tokens(xl, il, dl, gl, backend),
         mesh=spmd.mesh,
         in_specs=(
@@ -457,7 +456,7 @@ def spmd_scatter_add_tokens(
             spmd.data_spec(2),
         ),
         out_specs=spmd.data_spec(3),
-        check_rep=False,
+        check_vma=False,
     )(x, idx, delta, gate)
 
 
@@ -555,14 +554,14 @@ def _spmd_fused(
     # fully manual: fused dispatch only runs under pure DP (every fused dim
     # whole per device — models.blocks.fused_dispatch_supported), so any
     # model axis present has size 1 and replication over it is free
-    out, aux_stack = shard_map(
+    out, aux_stack = jax.shard_map(
         _local,
         mesh=spmd.mesh,
         in_specs=(
             spmd.data_spec(3), dspec, dspec, dspec, dspec, _pos_spec(positions, spmd),
         ),
         out_specs=(spmd.data_spec(3), aux_specs),
-        check_rep=False,
+        check_vma=False,
     )(x, decision.idx, decision.gate, decision.mask, logits, positions)
     return out, jax.tree.map(lambda a: jnp.mean(a, axis=0), aux_stack)
 
@@ -756,12 +755,12 @@ def _route_decode_spmd(
         )
 
     dspec1 = spmd.data_spec(1)
-    idx, gate, mask, scores = shard_map(
+    idx, gate, mask, scores = jax.shard_map(
         _decide_local,
         mesh=spmd.mesh,
         in_specs=(jax.tree.map(lambda _: P(), route_params), spmd.data_spec(3), dspec1),
         out_specs=(dspec1, dspec1, dspec1, dspec1),
-        check_rep=False,
+        check_vma=False,
     )(route_params, x, act)
 
     def _exec_local(xl, il, gl, ml, sl, cl, posl):
@@ -792,7 +791,7 @@ def _route_decode_spmd(
         )[2]
     )
     inner_specs = jax.tree.map(lambda _: P(spmd.data_axes), inner_struct)
-    out, new_caches, inner_stack = shard_map(
+    out, new_caches, inner_stack = jax.shard_map(
         _exec_local,
         mesh=spmd.mesh,
         in_specs=(
@@ -805,8 +804,8 @@ def _route_decode_spmd(
             _pos_spec(positions, spmd),
         ),
         out_specs=(spmd.data_spec(3), cache_specs, inner_specs),
-        check_rep=False,
-        auto=spmd.auto_axes,
+        check_vma=False,
+        axis_names=frozenset(spmd.data_axes),
     )(x, idx, gate, mask, scores, caches, positions)
     aux: Aux = dict(jax.tree.map(lambda a: jnp.mean(a, axis=0), inner_stack))
     # one decode_aux source of truth; it reads only mask/scores (idx here is

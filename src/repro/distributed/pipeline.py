@@ -18,7 +18,6 @@ from typing import Any, Callable, Tuple
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 
 def pipeline_forward(
@@ -76,12 +75,12 @@ def pipeline_forward(
         return outs[None]
 
     spec_p = jax.tree.map(lambda _: P(axis), params_stacked)
-    fn = shard_map(
+    fn = jax.shard_map(
         per_stage,
         mesh=mesh,
         in_specs=(spec_p, P(axis)),
         out_specs=P(axis),
-        check_rep=False,
+        check_vma=False,
     )
     # replicate microbatches to every stage (each consumes what it needs)
     x_rep = jnp.broadcast_to(x_micro[None], (n_stages,) + x_micro.shape)

@@ -22,7 +22,7 @@ from typing import Any, Dict, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 from repro.config import MeshConfig, ModelConfig
 from repro.utils import flatten_dict
@@ -152,13 +152,6 @@ class ShardCtx:
             return 1
         return int(self.mesh.shape[self.model_axis])
 
-    @property
-    def auto_axes(self) -> frozenset:
-        """Mesh axes left to GSPMD inside dispatch shard_map regions."""
-        if self.mesh is None:
-            return frozenset()
-        return frozenset(a for a in self.mesh.axis_names if a not in self.data_axes)
-
     def data_spec(self, ndim: int, batch_axis: int = 0) -> P:
         """PartitionSpec sharding ``batch_axis`` over the data axes."""
         spec: list = [None] * ndim
@@ -195,6 +188,13 @@ def shard_ctx(
     """
     if mesh is None:
         return ShardCtx(data_shards=int(data_shards or 1), fsdp=fsdp)
+    if any(t != AxisType.Auto for t in mesh.axis_types):
+        # the block code is written for GSPMD propagation: under explicit
+        # axes jax refuses its gathers and model-sharded contractions
+        raise ValueError(
+            f"mesh axis types {mesh.axis_types} are not all Auto; build the "
+            "mesh with repro.launch.mesh (jax.make_mesh defaults to Explicit)"
+        )
     bd = batch_axes(mesh)
     d = int(np.prod([mesh.shape[a] for a in bd])) if bd else 1
     if data_shards is not None and int(data_shards) != d:
@@ -217,15 +217,11 @@ def constrain_replicated(x: jax.Array) -> jax.Array:
 
     Used on the token-embedding table before the lookup: gathering from a
     sharded table makes the SPMD partitioner reshard the gather *output*,
-    which both replicates involuntarily and (in this XLA version) can emit
-    an invalid dynamic-slice. All-gathering the (comparatively tiny) table
-    first keeps the gather local. No-op without a mesh context.
+    which both replicates involuntarily and can emit an invalid
+    dynamic-slice. All-gathering the (comparatively tiny) table first keeps
+    the gather local. No-op without a mesh context.
     """
-    try:
-        mesh = jax.sharding.get_abstract_mesh()
-    except AttributeError:
-        return x
-    if mesh is None or not getattr(mesh, "axis_names", ()):
+    if not jax.sharding.get_abstract_mesh().axis_names:
         return x
     return jax.lax.with_sharding_constraint(x, P(*([None] * x.ndim)))
 
@@ -233,11 +229,8 @@ def constrain_replicated(x: jax.Array) -> jax.Array:
 def constrain_spec(x: jax.Array, *axes) -> jax.Array:
     """with_sharding_constraint under the ambient mesh, with divisibility
     validation (falls back to None per-dim). No-op outside a mesh context."""
-    try:
-        mesh = jax.sharding.get_abstract_mesh()
-    except AttributeError:
-        return x
-    if mesh is None or not getattr(mesh, "axis_names", ()):
+    mesh = jax.sharding.get_abstract_mesh()  # no axes outside jax.set_mesh
+    if not mesh.axis_names:
         return x
     spec = []
     for dim, ax in zip(x.shape, axes):
@@ -261,11 +254,8 @@ def constrain_batch(x: jax.Array) -> jax.Array:
     device). Model code calls this on scan carries; it is a no-op outside a
     mesh context (single-device tests).
     """
-    try:
-        mesh = jax.sharding.get_abstract_mesh()
-    except AttributeError:  # older jax
-        return x
-    if mesh is None or not getattr(mesh, "axis_names", ()):
+    mesh = jax.sharding.get_abstract_mesh()  # no axes outside jax.set_mesh
+    if not mesh.axis_names:
         return x
     bd = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
     if not bd:

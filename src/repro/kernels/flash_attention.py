@@ -22,12 +22,13 @@ projection, RoPE — so the capacity-sized attention runs on rows that never
 round-tripped through HBM. See DESIGN.md §Backend selection.
 
 Current blocking: only the capacity axis is tiled (``block_k``); each grid
-step stages the full ``(B, S, D)`` stream block and computes the dense
-capacity-sized softmax — correct in interpret mode at any size, VMEM-bound
-on real TPUs to roughly ``B·S·D ≲ 8M`` elements per core and re-reading
-``x`` once per capacity tile. S/B-axis tiling (streaming the gather
-accumulation like kernels/routing.py does) is the Mosaic follow-up; the
-bit-for-bit contract vs the xla backend likewise assumes the xla block
+step stages the full ``(B, S, D)`` stream block and the block's weights and
+computes the dense capacity-sized softmax — correct in interpret mode at any
+size, but not a Mosaic kernel: the body mirrors the xla block op for op,
+which Mosaic does not lower, so compiling it for a TPU raises
+``FUSED_NOT_COMPILED``. S/B-axis tiling (streaming the gather accumulation
+like kernels/routing.py does) and 2-D per-head bodies are the follow-up;
+the bit-for-bit contract vs the xla backend likewise assumes the xla block
 takes the dense-``attend`` path (capacity ≤ 2048, which ``ratio·S`` keeps
 true at the paper's settings).
 """
@@ -40,6 +41,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
@@ -49,6 +51,20 @@ DEFAULT_BLOCK_KV = 512
 # capacity-axis tile of the routed-attention kernel (module-level so tests
 # can exercise the padding tail by shrinking it)
 ROUTED_BLOCK_K = 128
+
+# Why the two fused-dispatch kernels run only in interpret mode. Their
+# bodies mirror the xla block op for op (3-5-D einsums, dynamic slices,
+# bf16-accumulated matmuls, 1-D broadcasts) and stage the whole (B, S, D)
+# stream and the block's weights in VMEM; Mosaic lowers none of that
+# (tests/test_tpu_compile.py keeps this refusal in view).
+FUSED_NOT_COMPILED = (
+    "MoDConfig.backend='pallas_fused': the fused routed-attention/routed-MLP "
+    "kernels do not compile for TPU (their bodies mirror the xla block op for "
+    "op and stage the whole stream and block weights in VMEM); they run only "
+    "in interpret mode. Use backend='pallas' or 'xla' on a chip. Serving is "
+    "unaffected: prefill dispatch falls back to the 'pallas' kernels and "
+    "decode routes with XLA ops."
+)
 
 
 def _flash_kernel(
@@ -156,25 +172,14 @@ def flash_attention(
         out_specs=pl.BlockSpec((1, 1, bq, hd), lambda b, h, i, j: (b, h, i, 0)),
         out_shape=jax.ShapeDtypeStruct((B, nq, Sq, hd), q.dtype),
         scratch_shapes=[
-            _vmem((bq, hd), jnp.float32),  # acc
-            _vmem((bq, 1), jnp.float32),  # running max
-            _vmem((bq, 1), jnp.float32),  # running denominator
+            pltpu.VMEM((bq, hd), jnp.float32),  # acc
+            pltpu.VMEM((bq, 1), jnp.float32),  # running max
+            pltpu.VMEM((bq, 1), jnp.float32),  # running denominator
         ],
         interpret=interpret,
     )(q_pos, kv_pos, qh, kh, vh)
     return jnp.swapaxes(out, 1, 2)
 
-
-def _vmem(shape, dtype):
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-
-        return pltpu.VMEM(shape, dtype)
-    except (ImportError, AttributeError):  # pragma: no cover
-        # jaxlib built without the TPU pallas extension (interpret-only
-        # environments); anything else propagates — a real VMEM failure
-        # must not silently demote the kernel's scratch space
-        return pl.MemorySpace.ANY  # type: ignore
 
 
 # ---------------------------------------------------------------------------
@@ -318,6 +323,8 @@ def _routed_attn_kernel(
 
 
 def _routed_attention_call(x, idx, pos_sub, params, spec: RoutedAttnSpec):
+    if not spec.interpret:
+        raise NotImplementedError(FUSED_NOT_COMPILED)
     B, S, D = x.shape
     k = idx.shape[1]
     bk = min(spec.block_k, k)

@@ -160,13 +160,16 @@ def paged_gather_dequant_xla(
 
 def _scatter_kernel(pid_ref, off_ref, rows_ref, page_ref, o_ref, *, n_slots: int):
     n = pl.program_id(0)
-    o_ref[...] = page_ref[...]
+    page = page_ref[0]  # (p, F)
+    row_of = jax.lax.broadcasted_iota(jnp.int32, page.shape, 0)
     # each physical page checks every slot for a write landing on it; B is
-    # the decode batch (small), so this is a short static loop
+    # the decode batch (small), so this is a short static loop. The row is
+    # written by a masked select, not a one-row store: Mosaic can only
+    # store at sublane offsets it can prove tile-aligned.
     for b in range(n_slots):
-        @pl.when(pid_ref[b] == n)
-        def _write(b=b):
-            o_ref[0, pl.dslice(off_ref[b], 1), :] = rows_ref[pl.dslice(b, 1), :]
+        hit = (row_of == off_ref[b]) & (pid_ref[b] == n)
+        page = jnp.where(hit, rows_ref[b : b + 1, :], page)
+    o_ref[0] = page
 
 
 def paged_scatter_rows_pallas(
@@ -182,16 +185,19 @@ def paged_scatter_rows_pallas(
     pid = jnp.take_along_axis(table, (pos // p)[:, None], axis=1)[:, 0]
     off = (pos % p).astype(jnp.int32)
     kernel = functools.partial(_scatter_kernel, n_slots=B)
-    return pl.pallas_call(
-        kernel,
+    # pid/off ride in SMEM (scalar prefetch)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
         grid=(N,),
         in_specs=[
-            pl.BlockSpec((B,), lambda n: (0,)),
-            pl.BlockSpec((B,), lambda n: (0,)),
-            pl.BlockSpec((B, F), lambda n: (0, 0)),
-            pl.BlockSpec((1, p, F), lambda n: (n, 0, 0)),
+            pl.BlockSpec((B, F), lambda n, pid, off: (0, 0)),
+            pl.BlockSpec((1, p, F), lambda n, pid, off: (n, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, p, F), lambda n: (n, 0, 0)),
+        out_specs=pl.BlockSpec((1, p, F), lambda n, pid, off: (n, 0, 0)),
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((N, p, F), pages.dtype),
         interpret=interpret,
     )(pid.astype(jnp.int32), off, rows.astype(pages.dtype), pages)

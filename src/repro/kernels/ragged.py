@@ -11,9 +11,9 @@ the batch dimension).  Three kernel families:
 - ``ragged_paged_flash_attention``: flash attention whose queries are the
   flat stream and whose K/V is read *directly out of the block-paged pool*
   — the page table rides the grid as a scalar-prefetch operand (the
-  ``kernels/paged.py`` trick) so grid step ``(s, h, i)`` DMAs exactly one
-  physical page of segment ``s``'s slot.  No per-slot ``(ctx,)`` view is
-  ever materialized.
+  ``kernels/paged.py`` trick) so grid step ``(s, i)`` DMAs exactly one
+  physical page (every kv head) of segment ``s``'s slot and updates all
+  query heads.  No per-slot ``(ctx,)`` view is ever materialized.
 - ``ragged_gather_rows`` / ``ragged_scatter_add_rows``: the MoD dispatch
   pair (paper Eq. 1) over the flat stream.  ``idx`` holds *flat* row
   indices grouped per segment ``(n_seg, k)``; ``-1`` marks masked
@@ -44,7 +44,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.flash_attention import NEG_INF, _vmem
+from repro.kernels.flash_attention import NEG_INF
 from repro.kernels.routing import _block_s
 
 
@@ -57,24 +57,27 @@ def _ragged_flash_kernel(
     offs_ref,  # (n_seg+1,) scalar-prefetch
     slot_ref,  # (n_seg,)   scalar-prefetch
     tbl_ref,  # (B, P)      scalar-prefetch
-    qpos_ref,  # (1, T+C)
-    q_ref,  # (1, T+C, 1, hd) — head axis selected by the BlockSpec
-    kpos_ref,  # (1, p)
-    k_ref,  # (1, p, 1, hd)
-    v_ref,  # (1, p, 1, hd)
-    o_ref,  # (1, 1, C, hd)
-    acc_ref,
-    m_ref,
-    l_ref,
-    *,
+    qpos_ref,  # (1, C, 1) this segment's query positions
+    q_ref,  # (1, nq, C, hd) this segment's queries, head-major
+    kpos_ref,  # (1, 1, p)
+    *refs,  # k (1, p, nkv, hd) [, k scales (1, p, nkv)], v [, v scales],
+    #         out (1, nq, C, hd), then the acc / max / denominator scratch
     scale: float,
     causal: bool,
     window: int,
     n_pages: int,
-    seg_cap: int,
+    quant: bool,
 ):
+    """One (segment, page) grid step over every query head. With ``quant``
+    the narrow K/V page is widened in VMEM right after the DMA (one f32
+    scale per page row per kv head — the same multiply the quantized oracle
+    uses), so quantized KV never crosses HBM at full width."""
+    if quant:
+        k_ref, ks_ref, v_ref, vs_ref, o_ref, acc_ref, m_ref, l_ref = refs
+    else:
+        k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref = refs
     s_id = pl.program_id(0)
-    i = pl.program_id(2)
+    i = pl.program_id(1)
 
     @pl.when(i == 0)
     def _init():
@@ -82,119 +85,52 @@ def _ragged_flash_kernel(
         m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    start = offs_ref[s_id]
-    seg_len = offs_ref[s_id + 1] - start
-    q = q_ref[0, pl.dslice(start, seg_cap), 0, :].astype(jnp.float32)  # (C, hd)
-    qp = qpos_ref[0, pl.dslice(start, seg_cap)]  # (C,)
-    kp = kpos_ref[0]  # (p,)
-    k = k_ref[0, :, 0, :].astype(jnp.float32)  # (p, hd)
-    v = v_ref[0, :, 0, :]  # (p, hd)
-
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) * scale  # (C, p)
+    seg_len = offs_ref[s_id + 1] - offs_ref[s_id]
+    qp = qpos_ref[0]  # (C, 1)
+    kp = kpos_ref[0]  # (1, p)
+    n_q, seg_cap = q_ref.shape[1], q_ref.shape[2]
+    n_kv = k_ref.shape[2]
     # rows past this segment's length hold the *next* segment's tokens —
     # mask them here; the wrapper drops their (garbage-zero) output rows
-    in_seg = jax.lax.broadcasted_iota(jnp.int32, (seg_cap, k.shape[0]), 0) < seg_len
-    valid = in_seg & (kp[None, :] >= 0) & (qp[:, None] >= 0)
+    in_seg = jax.lax.broadcasted_iota(jnp.int32, (seg_cap, kp.shape[1]), 0) < seg_len
+    valid = in_seg & (kp >= 0) & (qp >= 0)
     if causal:
-        valid &= kp[None, :] <= qp[:, None]
+        valid &= kp <= qp
     if window > 0:
-        valid &= qp[:, None] - kp[None, :] < window
-    s = jnp.where(valid, s, NEG_INF)
+        valid &= qp - kp < window
 
-    m_prev = m_ref[:, 0]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-    m_safe = jnp.where(m_new > NEG_INF / 2, m_new, 0.0)
-    p = jnp.exp(s - m_safe[:, None])
-    p = jnp.where(valid, p, 0.0)
-    corr = jnp.where(m_prev > NEG_INF / 2, jnp.exp(m_prev - m_safe), 0.0)
-    l_new = l_ref[:, 0] * corr + jnp.sum(p, axis=-1)
-    pv = jax.lax.dot_general(
-        p.astype(v.dtype), v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )
-    acc_ref[...] = acc_ref[...] * corr[:, None] + pv
-    m_ref[:, 0] = m_new
-    l_ref[:, 0] = l_new
+    for h in range(n_q):
+        hk = h * n_kv // n_q  # GQA: query head h reads kv head hk
+        q = q_ref[0, h].astype(jnp.float32)  # (C, hd)
+        k = k_ref[0, :, hk, :].astype(jnp.float32)  # (p, hd)
+        v = v_ref[0, :, hk, :]
+        if quant:
+            k = k * ks_ref[0, :, hk : hk + 1]
+            v = v.astype(jnp.float32) * vs_ref[0, :, hk : hk + 1]
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        ) * scale  # (C, p)
+        s = jnp.where(valid, s, NEG_INF)
+
+        m_prev = m_ref[h]  # (C, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        m_safe = jnp.where(m_new > NEG_INF / 2, m_new, 0.0)
+        p = jnp.exp(s - m_safe)
+        p = jnp.where(valid, p, 0.0)
+        corr = jnp.where(m_prev > NEG_INF / 2, jnp.exp(m_prev - m_safe), 0.0)
+        l_new = l_ref[h] * corr + jnp.sum(p, axis=-1, keepdims=True)
+        pv = jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        acc_ref[h] = acc_ref[h] * corr + pv
+        m_ref[h] = m_new
+        l_ref[h] = l_new
 
     @pl.when(i == n_pages - 1)
     def _finish():
-        l_fin = jnp.maximum(l_ref[:, 0], 1e-30)
-        o_ref[0, 0, :, :] = (acc_ref[...] / l_fin[:, None]).astype(o_ref.dtype)
-
-
-def _ragged_flash_quant_kernel(
-    offs_ref,  # (n_seg+1,) scalar-prefetch
-    slot_ref,  # (n_seg,)   scalar-prefetch
-    tbl_ref,  # (B, P)      scalar-prefetch
-    qpos_ref,  # (1, T+C)
-    q_ref,  # (1, T+C, 1, hd)
-    kpos_ref,  # (1, p)
-    k_ref,  # (1, p, 1, hd) narrow (int8 | fp8)
-    ks_ref,  # (1, p, 1) f32 per-(page-row, kv-head) scales
-    v_ref,  # (1, p, 1, hd) narrow
-    vs_ref,  # (1, p, 1) f32
-    o_ref,  # (1, 1, C, hd)
-    acc_ref,
-    m_ref,
-    l_ref,
-    *,
-    scale: float,
-    causal: bool,
-    window: int,
-    n_pages: int,
-    seg_cap: int,
-):
-    """`_ragged_flash_kernel` with fused dequantization: the narrow K/V
-    page is widened in VMEM right after the DMA (one f32 scale per page
-    row per kv head — the same multiply the quantized oracle uses), so
-    quantized KV never crosses HBM at full width."""
-    s_id = pl.program_id(0)
-    i = pl.program_id(2)
-
-    @pl.when(i == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
-        l_ref[...] = jnp.zeros_like(l_ref)
-
-    start = offs_ref[s_id]
-    seg_len = offs_ref[s_id + 1] - start
-    q = q_ref[0, pl.dslice(start, seg_cap), 0, :].astype(jnp.float32)  # (C, hd)
-    qp = qpos_ref[0, pl.dslice(start, seg_cap)]  # (C,)
-    kp = kpos_ref[0]  # (p,)
-    k = k_ref[0, :, 0, :].astype(jnp.float32) * ks_ref[0, :, 0][:, None]  # (p, hd)
-    v = v_ref[0, :, 0, :].astype(jnp.float32) * vs_ref[0, :, 0][:, None]
-
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) * scale  # (C, p)
-    in_seg = jax.lax.broadcasted_iota(jnp.int32, (seg_cap, k.shape[0]), 0) < seg_len
-    valid = in_seg & (kp[None, :] >= 0) & (qp[:, None] >= 0)
-    if causal:
-        valid &= kp[None, :] <= qp[:, None]
-    if window > 0:
-        valid &= qp[:, None] - kp[None, :] < window
-    s = jnp.where(valid, s, NEG_INF)
-
-    m_prev = m_ref[:, 0]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-    m_safe = jnp.where(m_new > NEG_INF / 2, m_new, 0.0)
-    p = jnp.exp(s - m_safe[:, None])
-    p = jnp.where(valid, p, 0.0)
-    corr = jnp.where(m_prev > NEG_INF / 2, jnp.exp(m_prev - m_safe), 0.0)
-    l_new = l_ref[:, 0] * corr + jnp.sum(p, axis=-1)
-    pv = jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )
-    acc_ref[...] = acc_ref[...] * corr[:, None] + pv
-    m_ref[:, 0] = m_new
-    l_ref[:, 0] = l_new
-
-    @pl.when(i == n_pages - 1)
-    def _finish():
-        l_fin = jnp.maximum(l_ref[:, 0], 1e-30)
-        o_ref[0, 0, :, :] = (acc_ref[...] / l_fin[:, None]).astype(o_ref.dtype)
+        l_fin = jnp.maximum(l_ref[...], 1e-30)
+        o_ref[0] = (acc_ref[...] / l_fin).astype(o_ref.dtype)
 
 
 def flat_segment_ids(row_offsets: jax.Array, total: int) -> jax.Array:
@@ -235,35 +171,31 @@ def ragged_paged_flash_attention(
     scale = scale if scale is not None else 1.0 / (hd**0.5)
     C = int(seg_cap)
 
-    # pad the flat stream by one segment capacity so the in-kernel dynamic
-    # slice at the last segment never reads out of bounds
-    qp2 = jnp.pad(q_pos.astype(jnp.int32), (0, C), constant_values=-1)[None]
-    qf = jnp.pad(q, ((0, C), (0, 0), (0, 0)))[None]  # (1, T+C, nq, hd)
+    # window each segment's C query rows out of the flat stream (padded by
+    # one capacity so the last window stays in bounds), head-major, so every
+    # block's last two dims are whole array dims (the Mosaic tiling rule)
+    win = row_offsets[:-1, None].astype(jnp.int32) + jnp.arange(C, dtype=jnp.int32)
+    qf = jnp.pad(q, ((0, C), (0, 0), (0, 0)))
+    q_seg = jnp.take(qf, win, axis=0).transpose(0, 2, 1, 3)  # (n_seg, nq, C, hd)
+    qp_seg = jnp.take(
+        jnp.pad(q_pos.astype(jnp.int32), (0, C), constant_values=-1), win
+    )[..., None]  # (n_seg, C, 1)
 
-    kv_spec = pl.BlockSpec(
-        (1, p, 1, hd),
-        lambda s, h, i, offs, slot, tbl, _nkv=nkv, _nq=nq: (
-            tbl[slot[s], i], 0, h * _nkv // _nq, 0,
-        ),
-    )
-    sc_spec = pl.BlockSpec(
-        (1, p, 1),
-        lambda s, h, i, offs, slot, tbl, _nkv=nkv, _nq=nq: (
-            tbl[slot[s], i], 0, h * _nkv // _nq,
-        ),
-    )
+    def page(s, i, offs, slot, tbl):
+        return tbl[slot[s], i]
+
+    kv_spec = pl.BlockSpec((1, p, nkv, hd), lambda *a: (page(*a), 0, 0, 0))
+    sc_spec = pl.BlockSpec((1, p, nkv), lambda *a: (page(*a), 0, 0))
     in_specs = [
-        pl.BlockSpec((1, T + C), lambda s, h, i, offs, slot, tbl: (0, 0)),
-        pl.BlockSpec((1, T + C, 1, hd), lambda s, h, i, offs, slot, tbl: (0, 0, h, 0)),
-        pl.BlockSpec(
-            (1, p), lambda s, h, i, offs, slot, tbl: (tbl[slot[s], i], 0)
-        ),
+        pl.BlockSpec((1, C, 1), lambda s, i, *_: (s, 0, 0)),
+        pl.BlockSpec((1, nq, C, hd), lambda s, i, *_: (s, 0, 0, 0)),
+        pl.BlockSpec((1, 1, p), lambda *a: (page(*a), 0, 0)),
         kv_spec,
         *([sc_spec] if quant else []),
         kv_spec,
         *([sc_spec] if quant else []),
     ]
-    operands = [pos_pages, k_pages]
+    operands = [pos_pages.astype(jnp.int32).reshape(N, 1, p), k_pages]
     if quant:
         operands.append(k_scales.astype(jnp.float32))
     operands.append(v_pages)
@@ -272,19 +204,18 @@ def ragged_paged_flash_attention(
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(n_seg, nq, P),
+        grid=(n_seg, P),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, C, hd), lambda s, h, i, offs, slot, tbl: (s, h, 0, 0)),
+        out_specs=pl.BlockSpec((1, nq, C, hd), lambda s, i, *_: (s, 0, 0, 0)),
         scratch_shapes=[
-            _vmem((C, hd), jnp.float32),
-            _vmem((C, 1), jnp.float32),
-            _vmem((C, 1), jnp.float32),
+            pltpu.VMEM((nq, C, hd), jnp.float32),
+            pltpu.VMEM((nq, C, 1), jnp.float32),
+            pltpu.VMEM((nq, C, 1), jnp.float32),
         ],
     )
     kernel = functools.partial(
-        _ragged_flash_quant_kernel if quant else _ragged_flash_kernel,
-        scale=float(scale), causal=bool(causal), window=int(window),
-        n_pages=P, seg_cap=C,
+        _ragged_flash_kernel, scale=float(scale), causal=bool(causal),
+        window=int(window), n_pages=P, quant=quant,
     )
 
     out = pl.pallas_call(
@@ -293,7 +224,7 @@ def ragged_paged_flash_attention(
         out_shape=jax.ShapeDtypeStruct((n_seg, nq, C, hd), q.dtype),
         interpret=interpret,
     )(row_offsets.astype(jnp.int32), seg_slot.astype(jnp.int32),
-      table.astype(jnp.int32), qp2, qf, *operands)
+      table.astype(jnp.int32), qp_seg, q_seg, *operands)
 
     # scatter the (n_seg, C) segment rows back onto the flat stream
     seg_id = flat_segment_ids(row_offsets, T)
@@ -315,10 +246,10 @@ def _ragged_gather_kernel(idx_ref, x_ref, o_ref, acc_ref, *, bs: int, n_blocks: 
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    idx = idx_ref[0, :]  # (k,) flat row ids; -1 never matches any row
+    idx = idx_ref[0]  # (k, 1) flat row ids; -1 never matches any row
     k = idx.shape[0]
     rows = jax.lax.broadcasted_iota(jnp.int32, (k, bs), 1) + j * bs
-    P = (rows == idx[:, None]).astype(jnp.float32)
+    P = (rows == idx).astype(jnp.float32)
     acc_ref[...] += jax.lax.dot_general(
         P, x_ref[...].astype(jnp.float32), (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
@@ -345,14 +276,14 @@ def ragged_gather_rows(
         kernel,
         grid=(n_seg, n_blocks),
         in_specs=[
-            pl.BlockSpec((1, k), lambda s, j: (s, 0)),
+            pl.BlockSpec((1, k, 1), lambda s, j: (s, 0, 0)),
             pl.BlockSpec((bs, D), lambda s, j: (j, 0)),
         ],
         out_specs=pl.BlockSpec((1, k, D), lambda s, j: (s, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((n_seg, k, D), x.dtype),
-        scratch_shapes=[_vmem((k, D), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((k, D), jnp.float32)],
         interpret=interpret,
-    )(idx.astype(jnp.int32), x)
+    )(idx.astype(jnp.int32).reshape(n_seg, k, 1), x)
 
 
 def _ragged_scatter_kernel(
@@ -365,11 +296,11 @@ def _ragged_scatter_kernel(
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    idx = idx_ref[0, :]  # (k,)
-    k = idx.shape[0]
+    idx = idx_ref[0]  # (1, k)
+    k = idx.shape[1]
     rows = jax.lax.broadcasted_iota(jnp.int32, (bs, k), 0) + j * bs
-    P = (rows == idx[None, :]).astype(jnp.float32)  # -1 matches nothing
-    gated = gate_ref[0][:, None] * d_ref[0].astype(jnp.float32)  # (k, D)
+    P = (rows == idx).astype(jnp.float32)  # -1 matches nothing
+    gated = gate_ref[0] * d_ref[0].astype(jnp.float32)  # (k, 1) * (k, D)
     acc_ref[...] += jax.lax.dot_general(
         P, gated, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
     )
@@ -396,16 +327,17 @@ def ragged_scatter_add_rows(
         kernel,
         grid=(T // bs, n_seg),
         in_specs=[
-            pl.BlockSpec((1, k), lambda j, s: (s, 0)),
-            pl.BlockSpec((1, k), lambda j, s: (s, 0)),
+            pl.BlockSpec((1, 1, k), lambda j, s: (s, 0, 0)),
+            pl.BlockSpec((1, k, 1), lambda j, s: (s, 0, 0)),
             pl.BlockSpec((1, k, D), lambda j, s: (s, 0, 0)),
             pl.BlockSpec((bs, D), lambda j, s: (j, 0)),
         ],
         out_specs=pl.BlockSpec((bs, D), lambda j, s: (j, 0)),
         out_shape=jax.ShapeDtypeStruct((T, D), x.dtype),
-        scratch_shapes=[_vmem((bs, D), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((bs, D), jnp.float32)],
         interpret=interpret,
-    )(idx.astype(jnp.int32), gate.astype(jnp.float32), delta, x)
+    )(idx.astype(jnp.int32).reshape(n_seg, 1, k),
+      gate.astype(jnp.float32).reshape(n_seg, k, 1), delta, x)
 
 
 # ---------------------------------------------------------------------------
@@ -455,13 +387,15 @@ def ragged_paged_scatter_rows_xla(
 
 def _ragged_ps_kernel(pid_ref, off_ref, rows_ref, page_ref, o_ref, *, n_rows: int):
     n = pl.program_id(0)
-    o_ref[...] = page_ref[...]
+    page = page_ref[0]  # (p, F)
+    row_of = jax.lax.broadcasted_iota(jnp.int32, page.shape, 0)
     # every physical page checks each write row; W is the step's token
-    # budget (small), so this is a short static loop
+    # budget (small), so this is a short static loop of masked selects
+    # (kernels/paged.py: no one-row stores at unprovable sublane offsets)
     for w in range(n_rows):
-        @pl.when(pid_ref[w] == n)
-        def _write(w=w):
-            o_ref[0, pl.dslice(off_ref[w], 1), :] = rows_ref[pl.dslice(w, 1), :]
+        hit = (row_of == off_ref[w]) & (pid_ref[w] == n)
+        page = jnp.where(hit, rows_ref[w : w + 1, :], page)
+    o_ref[0] = page
 
 
 def ragged_paged_scatter_rows_pallas(
@@ -475,16 +409,19 @@ def ragged_paged_scatter_rows_pallas(
     N, p, F = pages.shape
     W = pid.shape[0]
     kernel = functools.partial(_ragged_ps_kernel, n_rows=W)
-    return pl.pallas_call(
-        kernel,
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
         grid=(N,),
         in_specs=[
-            pl.BlockSpec((W,), lambda n: (0,)),
-            pl.BlockSpec((W,), lambda n: (0,)),
-            pl.BlockSpec((W, F), lambda n: (0, 0)),
-            pl.BlockSpec((1, p, F), lambda n: (n, 0, 0)),
+            pl.BlockSpec((W, F), lambda n, pid, off: (0, 0)),
+            pl.BlockSpec((1, p, F), lambda n, pid, off: (n, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, p, F), lambda n: (n, 0, 0)),
+        out_specs=pl.BlockSpec((1, p, F), lambda n, pid, off: (n, 0, 0)),
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((N, p, F), pages.dtype),
         interpret=interpret,
-    )(pid.astype(jnp.int32), off.astype(jnp.int32), rows.astype(pages.dtype), pages)
+    )(pid.astype(jnp.int32), off.astype(jnp.int32),
+      rows.astype(pages.dtype), pages)

@@ -31,8 +31,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
-
-from repro.kernels.swiglu import _vmem
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _block_s(seq_len: int, block_s: int) -> int:
@@ -55,11 +54,11 @@ def _gather_kernel(idx_ref, x_ref, o_ref, acc_ref, *, bs: int, n_blocks: int):
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    idx = idx_ref[0, :]  # (k,)
+    idx = idx_ref[0]  # (k, 1)
     k = idx.shape[0]
     # P[i, r] = 1 iff selected row i lives at row r of this S-block
     rows = jax.lax.broadcasted_iota(jnp.int32, (k, bs), 1) + j * bs
-    P = (rows == idx[:, None]).astype(jnp.float32)
+    P = (rows == idx).astype(jnp.float32)
     acc_ref[...] += jax.lax.dot_general(
         P,
         x_ref[0].astype(jnp.float32),
@@ -82,14 +81,14 @@ def _gather_call(x, idx, interpret, block_s):
         kernel,
         grid=(B, n_blocks),
         in_specs=[
-            pl.BlockSpec((1, k), lambda b, j: (b, 0)),
+            pl.BlockSpec((1, k, 1), lambda b, j: (b, 0, 0)),
             pl.BlockSpec((1, bs, D), lambda b, j: (b, j, 0)),
         ],
         out_specs=pl.BlockSpec((1, k, D), lambda b, j: (b, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, k, D), x.dtype),
-        scratch_shapes=[_vmem((k, D), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((k, D), jnp.float32)],
         interpret=interpret,
-    )(idx, x)
+    )(idx.reshape(B, k, 1), x)
 
 
 # ---------------------------------------------------------------------------
@@ -99,11 +98,11 @@ def _gather_call(x, idx, interpret, block_s):
 
 def _scatter_kernel(idx_ref, gate_ref, x_ref, d_ref, o_ref, *, bs: int):
     j = pl.program_id(1)
-    idx = idx_ref[0, :]  # (k,)
-    k = idx.shape[0]
+    idx = idx_ref[0]  # (1, k)
+    k = idx.shape[1]
     rows = jax.lax.broadcasted_iota(jnp.int32, (bs, k), 0) + j * bs
-    P = (rows == idx[None, :]).astype(jnp.float32)  # (bs, k)
-    gated = gate_ref[0][:, None] * d_ref[0].astype(jnp.float32)  # (k, D)
+    P = (rows == idx).astype(jnp.float32)  # (bs, k)
+    gated = gate_ref[0] * d_ref[0].astype(jnp.float32)  # (k, 1) * (k, D)
     upd = jax.lax.dot_general(
         P, gated, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
     )
@@ -119,15 +118,15 @@ def _scatter_call(x, idx, delta, gate, interpret, block_s):
         kernel,
         grid=(B, S // bs),
         in_specs=[
-            pl.BlockSpec((1, k), lambda b, j: (b, 0)),
-            pl.BlockSpec((1, k), lambda b, j: (b, 0)),
+            pl.BlockSpec((1, 1, k), lambda b, j: (b, 0, 0)),
+            pl.BlockSpec((1, k, 1), lambda b, j: (b, 0, 0)),
             pl.BlockSpec((1, bs, D), lambda b, j: (b, j, 0)),
             pl.BlockSpec((1, k, D), lambda b, j: (b, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, bs, D), lambda b, j: (b, j, 0)),
         out_shape=jax.ShapeDtypeStruct((B, S, D), x.dtype),
         interpret=interpret,
-    )(idx, gate.astype(jnp.float32), x, delta)
+    )(idx.reshape(B, 1, k), gate.astype(jnp.float32).reshape(B, k, 1), x, delta)
 
 
 # ---------------------------------------------------------------------------

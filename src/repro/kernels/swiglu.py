@@ -23,10 +23,11 @@ from typing import Dict, NamedTuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 # the bitwise models.layers mirrors + float0 helper are shared with the
 # routed-attention kernel so the two fused halves can never drift apart
-from repro.kernels.flash_attention import _float0, _mirror_rmsnorm
+from repro.kernels.flash_attention import FUSED_NOT_COMPILED, _float0, _mirror_rmsnorm
 
 
 def _swiglu_kernel(x_ref, wg_ref, wu_ref, wd_ref, o_ref, acc_ref, *, n_f_blocks: int):
@@ -80,21 +81,10 @@ def swiglu(
         ],
         out_specs=pl.BlockSpec((bm, D), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((M, D), x.dtype),
-        scratch_shapes=[_vmem((bm, D), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((bm, D), jnp.float32)],
         interpret=interpret,
     )(x, w_gate, w_up, w_down)
 
-
-def _vmem(shape, dtype):
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-
-        return pltpu.VMEM(shape, dtype)
-    except (ImportError, AttributeError):  # pragma: no cover
-        # jaxlib built without the TPU pallas extension (interpret-only
-        # environments); anything else propagates — a real VMEM failure
-        # must not silently demote the kernel's scratch space
-        return pl.MemorySpace.ANY  # type: ignore
 
 
 # ---------------------------------------------------------------------------
@@ -171,6 +161,8 @@ def _block_div(seq_len: int, block_s: int) -> int:
 
 
 def _routed_mlp_call(x, h_sub, a_sub, idx, gate, params, spec: RoutedMlpSpec):
+    if not spec.interpret:
+        raise NotImplementedError(FUSED_NOT_COMPILED)
     B, S, D = x.shape
     k = idx.shape[1]
     F = params["w_up"].shape[1]
@@ -198,7 +190,7 @@ def _routed_mlp_call(x, h_sub, a_sub, idx, gate, params, spec: RoutedMlpSpec):
         in_specs=in_specs,
         out_specs=pl.BlockSpec((B, bs, D), lambda j: (0, j, 0)),
         out_shape=jax.ShapeDtypeStruct((B, S, D), x.dtype),
-        scratch_shapes=[_vmem((B, k, D), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((B, k, D), jnp.float32)],
         interpret=spec.interpret,
     )(*args)
 

@@ -52,7 +52,6 @@ from repro.launch import hlo_analysis
 from repro.launch.mesh import make_production_mesh
 from repro.models import api
 from repro.train.loop import make_train_step, train_state_specs
-from repro.utils import cost_analysis_dict, mesh_scope
 
 ASSIGNED_ARCHS = [
     "granite-8b",
@@ -165,7 +164,7 @@ def run_cell(
     t0 = time.time()
     # ambient mesh lets model-internal sharding constraints (scan carries)
     # resolve bare PartitionSpecs — see distributed.sharding.constrain_batch
-    with mesh_scope(mesh):
+    with jax.set_mesh(mesh):
         lowered = lower_cell(cfg, shape_name, mesh, mcfg, microbatches=microbatches)
         rec["lower_s"] = round(time.time() - t0, 2)
         t0 = time.time()
@@ -179,7 +178,7 @@ def run_cell(
         "temp_bytes": int(ma.temp_size_in_bytes),
         "alias_bytes": int(ma.alias_size_in_bytes),
     }
-    ca = cost_analysis_dict(compiled)
+    ca = compiled.cost_analysis() or {}
     rec["cost"] = {
         "flops": float(ca.get("flops", 0.0)),
         "bytes_accessed": float(ca.get("bytes accessed", 0.0)),

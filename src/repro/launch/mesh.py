@@ -6,20 +6,14 @@ from __future__ import annotations
 from typing import Optional
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 from repro.config import MeshConfig
 
-try:  # jax >= 0.5: explicit axis types
-    from jax.sharding import AxisType
 
-    def _axis_kw(n: int):
-        return {"axis_types": (AxisType.Auto,) * n}
-except ImportError:  # older jax: Auto is the only (implicit) behaviour
-    AxisType = None
-
-    def _axis_kw(n: int):
-        return {}
+def _axis_kw(n: int):
+    # every axis stays under GSPMD (Auto); explicit sharding is not used
+    return {"axis_types": (AxisType.Auto,) * n}
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:

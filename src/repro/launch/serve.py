@@ -15,24 +15,41 @@ this driver and ``benchmarks/serving.py`` expose the same surface.
 
   PYTHONPATH=src python -m repro.launch.serve --arch mod-paper-60m \
       --smoke --batch 8 --prompt-len 32 --gen 32 --requests 16
+
+``main(argv, **engine_overrides)`` is also the programmatic entry point
+(``chip_smoke.py`` drives it): it returns the :class:`ServeRun`, and the
+keyword overrides replace :class:`~repro.serve.EngineConfig` fields that
+have no flag (``paged_backend``, ``logit_tap``).
 """
 from __future__ import annotations
 
 import argparse
 import dataclasses
+from typing import Any, List, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.checkpoint import CheckpointManager
-from repro.config import get_config, smoke_config
+from repro.config import ModelConfig, get_config, smoke_config
 from repro.data.synthetic import SyntheticLM
 from repro.models import api
 from repro.serve import EngineConfig, Request, ServingEngine, add_engine_args
+from repro.utils import enable_compile_cache
 
 
-def main() -> None:
+@dataclasses.dataclass
+class ServeRun:
+    """What one serving run produced: the resolved model config, the
+    engine (its stats and pool) and every finished request."""
+
+    cfg: ModelConfig
+    engine: ServingEngine
+    outputs: List[Any]
+
+
+def parse_args(argv: Optional[Sequence[str]] = None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="mod-paper-60m")
     ap.add_argument("--smoke", action="store_true")
@@ -72,8 +89,12 @@ def main() -> None:
                          "stragglers, preemption storms) with this seed; "
                          "-1 = off")
     add_engine_args(ap)
-    args = ap.parse_args()
+    return ap.parse_args(argv)
 
+
+def run(args: argparse.Namespace, **engine_overrides: Any) -> ServeRun:
+    """Build the model and engine from parsed flags and serve the request
+    stream; ``engine_overrides`` replace EngineConfig fields."""
     cfg = get_config(args.arch)
     if args.smoke:
         cfg = smoke_config(cfg)
@@ -108,9 +129,9 @@ def main() -> None:
         from repro.serve import FaultInjector
 
         injector = FaultInjector.seeded(args.inject_faults)
-    ecfg = EngineConfig.from_args(
-        args, batch_size=args.batch, ctx=ctx, mesh=mesh, fault_injector=injector
-    )
+    overrides = dict(mesh=mesh, fault_injector=injector)
+    overrides.update(engine_overrides)
+    ecfg = EngineConfig.from_args(args, batch_size=args.batch, ctx=ctx, **overrides)
     engine = ServingEngine(params, cfg, engine=ecfg)
 
     outputs = engine.run_stream(
@@ -120,7 +141,13 @@ def main() -> None:
          for i in range(n_requests)],
         args.arrival_every,
     )
+    return ServeRun(cfg, engine, outputs)
 
+
+def report(args: argparse.Namespace, result: ServeRun) -> None:
+    cfg, engine, outputs = result.cfg, result.engine, result.outputs
+    ctx = args.prompt_len + args.gen
+    injector = engine.engine_config.fault_injector
     s = engine.stats()
     lat = np.asarray([o.residency_steps for o in outputs], np.float64)
     wait = np.asarray([o.queue_steps for o in outputs], np.float64)
@@ -182,6 +209,14 @@ def main() -> None:
         print(f"[serve] faults fired: {fired or 'none'}")
     first = min(outputs, key=lambda o: o.uid)
     print(f"[serve] sample continuation: {first.tokens[-10:].tolist()}")
+
+
+def main(argv: Optional[Sequence[str]] = None, **engine_overrides: Any) -> ServeRun:
+    args = parse_args(argv)
+    enable_compile_cache()
+    result = run(args, **engine_overrides)
+    report(args, result)
+    return result
 
 
 if __name__ == "__main__":

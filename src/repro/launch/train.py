@@ -8,11 +8,14 @@ TPU pod slice the same file runs under ``jax.distributed.initialize()``
 
   PYTHONPATH=src python -m repro.launch.train --arch mod-paper-60m \
       --steps 200 --batch 8 --seq 128 --ckpt-dir /tmp/ckpt
+
+``main(argv)`` returns the last step's metrics (``chip_smoke.py`` drives it).
 """
 from __future__ import annotations
 
 import argparse
 import dataclasses
+from typing import Dict, Optional, Sequence
 
 import jax
 import numpy as np
@@ -24,10 +27,10 @@ from repro.data.synthetic import SyntheticLM
 from repro.distributed.sharding import batch_shardings, state_shardings
 from repro.launch.mesh import make_mesh
 from repro.train.loop import Trainer, make_train_step
-from repro.utils import mesh_scope
+from repro.utils import enable_compile_cache
 
 
-def main() -> None:
+def main(argv: Optional[Sequence[str]] = None) -> Dict[str, float]:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="mod-paper-60m")
     ap.add_argument("--smoke", action="store_true", help="reduced config of the arch family")
@@ -41,7 +44,8 @@ def main() -> None:
     ap.add_argument("--fsdp", action="store_true")
     ap.add_argument("--dtype", default=None, help="override model dtype (e.g. float32)")
     ap.add_argument("--ckpt-dir", default="/tmp/repro_ckpt")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.smoke:
@@ -75,7 +79,7 @@ def main() -> None:
     from repro.distributed.sharding import shard_ctx
 
     spmd = shard_ctx(mesh, fsdp=args.fsdp)
-    with mesh_scope(mesh):
+    with jax.set_mesh(mesh):
         step_raw = make_train_step(cfg, tcfg, spmd=spmd)
         # shard the state according to the rules; metrics replicated
         import jax.numpy as jnp
@@ -104,6 +108,7 @@ def main() -> None:
         print(f"[train] done at step {int(state['step'])}: "
               f"ce={metrics.get('ce', float('nan')):.4f}")
     loader.close()
+    return metrics
 
 
 if __name__ == "__main__":

@@ -43,7 +43,16 @@ def make_train_step(
     """``spmd`` (``distributed.sharding.ShardCtx``) makes every MoD site's
     routing decision + dispatch run per data shard inside shard_map while
     dense blocks / aux losses stay under GSPMD — pass it when the step is
-    jitted over a real mesh (launch/train.py)."""
+    jitted over a real mesh (launch/train.py).
+
+    Raises ValueError up front where the step would run the fused-dispatch
+    kernels compiled: they run only in interpret mode (CPU)."""
+    from repro.kernels.flash_attention import FUSED_NOT_COMPILED
+    from repro.kernels.ops import on_cpu
+    from repro.models.blocks import fused_dispatch_supported
+
+    if cfg.mod.enabled and fused_dispatch_supported(cfg, spmd) and not on_cpu():
+        raise ValueError(FUSED_NOT_COMPILED)
     ocfg = tcfg.optim
 
     def loss_fn(params, batch, step):

@@ -2,26 +2,34 @@
 from __future__ import annotations
 
 import json
-from typing import Any, Dict, Iterator, Tuple
+import os
+from pathlib import Path
+from typing import Any, Dict, Iterator
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+# The checkout root (src/repro/utils.py -> ../..): home of the default
+# persistent compilation cache and of other run-time caches, all listed in
+# .gitignore.
+REPO_ROOT = Path(__file__).resolve().parents[2]
 
-def mesh_scope(mesh):
-    """Ambient-mesh context across jax versions: ``jax.set_mesh`` where
-    available, else the legacy ``with mesh:`` global-mesh context."""
-    return jax.set_mesh(mesh) if hasattr(jax, "set_mesh") else mesh
 
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
 
-def cost_analysis_dict(compiled) -> Dict[str, Any]:
-    """compiled.cost_analysis() across jax versions (old jax returns a
-    one-element list of dicts, new jax the dict itself)."""
-    ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and no
+    other directory is set here. Otherwise the cache lives at the fixed
+    ``.jax_cache/`` in the checkout: the path is part of what a later run
+    must find again, so it never holds a pid, a timestamp or a temporary
+    directory. Every entry point calls this before its first compile.
+    """
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = str(REPO_ROOT / ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def key_iter(seed_or_key) -> Iterator[jax.Array]:

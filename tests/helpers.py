@@ -43,16 +43,11 @@ def property_cases(argnames, fallback, build, max_examples=25):
     return deco
 
 
-def abstract_mesh_compat(shape, axes):
-    """AbstractMesh across jax versions (axis_types only where supported)."""
-    from jax.sharding import AbstractMesh
+def auto_abstract_mesh(shape, axes):
+    """AbstractMesh with every axis under GSPMD (Auto)."""
+    from jax.sharding import AbstractMesh, AxisType
 
-    try:
-        from jax.sharding import AxisType
-
-        return AbstractMesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
-    except ImportError:  # old signature: tuple of (name, size) pairs
-        return AbstractMesh(tuple(zip(axes, shape)))
+    return AbstractMesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def tiny_cfg(**kw) -> ModelConfig:

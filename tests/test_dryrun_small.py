@@ -22,7 +22,6 @@ SCRIPT = textwrap.dedent(
     from repro.models import api
     from repro.train.loop import make_train_step, train_state_specs
     from repro.launch.mesh import make_mesh
-    from repro.utils import cost_analysis_dict, mesh_scope
 
     mcfg = MeshConfig(pod=2, data=2, model=2, fsdp=True)
     mesh = make_mesh(mcfg)
@@ -39,10 +38,10 @@ SCRIPT = textwrap.dedent(
     st = train_state_specs(jax.random.PRNGKey(0), cfg)
     st_sh = state_shardings(st, mesh, mcfg)
     b_sh = batch_shardings(specs, mesh)
-    with mesh_scope(mesh):
+    with jax.set_mesh(mesh):
         c = jax.jit(make_train_step(cfg, tcfg), in_shardings=(st_sh, b_sh),
                     out_shardings=(st_sh, None), donate_argnums=(0,)).lower(st, specs).compile()
-    out["train_flops"] = float(cost_analysis_dict(c).get("flops", 0))
+    out["train_flops"] = float(c.cost_analysis().get("flops", 0))
     out["train_temp"] = int(c.memory_analysis().temp_size_in_bytes)
 
     # --- serve step ---
@@ -55,7 +54,7 @@ SCRIPT = textwrap.dedent(
     tp_sh = batch_shardings({{"token": tok, "pos": pos}}, mesh)
     def serve(p, c, t, q):
         return api.model_decode(p, c, cfg, t, q)
-    with mesh_scope(mesh):
+    with jax.set_mesh(mesh):
         c2 = jax.jit(serve, in_shardings=(p_sh, c_sh, tp_sh["token"], tp_sh["pos"]),
                      out_shardings=(None, c_sh, None), donate_argnums=(1,)).lower(
                          ps, caches, tok, pos).compile()

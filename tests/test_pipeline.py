@@ -26,14 +26,10 @@ SCRIPT = textwrap.dedent(
 
     from repro.distributed.pipeline import pipeline_forward
     from repro.optim.compression import compressed_psum, init_error_feedback
-    from jax.experimental.shard_map import shard_map
+    from jax.sharding import AxisType
     from jax.sharding import PartitionSpec as P
 
-    try:
-        from jax.sharding import AxisType
-        mesh = jax.make_mesh((4,), ("pod",), axis_types=(AxisType.Auto,))
-    except ImportError:
-        mesh = jax.make_mesh((4,), ("pod",))
+    mesh = jax.make_mesh((4,), ("pod",), axis_types=(AxisType.Auto,))
     out = {}
 
     # --- pipeline: 4 stages of y = x @ W_i + b_i, compare vs sequential ----
@@ -59,8 +55,8 @@ SCRIPT = textwrap.dedent(
         avg, new_e = compressed_psum({"g": g_local[0]}, "pod", {"g": e_local[0]})
         return avg["g"][None], new_e["g"][None]
 
-    fn = shard_map(reduce_fn, mesh=mesh, in_specs=(P("pod"), P("pod")),
-                   out_specs=(P("pod"), P("pod")), check_rep=False)
+    fn = jax.shard_map(reduce_fn, mesh=mesh, in_specs=(P("pod"), P("pod")),
+                       out_specs=(P("pod"), P("pod")), check_vma=False)
     avg, err = fn(g, jnp.zeros_like(g))
     true_mean = jnp.mean(g, axis=0)
     # each device holds the same (approximate) mean

@@ -251,7 +251,13 @@ def test_fused_padding_tail(block_k):
     """Capacity NOT a multiple of the kernel's capacity tile: k=20 over
     block_k ∈ {8, 16} pads the q-tile axis (idx/pos = -1). Padded rows must
     neither perturb real rows (f32 bit-for-bit vs xla) nor leak through the
-    scatter."""
+    scatter.
+
+    The bound is a tight f32 tolerance, not bit equality: XLA 0.9 fuses
+    the xla backend's composition differently from the kernel's (same op
+    sequence, different fusion), and the two drift by ulps (measured
+    <= 7.6e-6 absolute on 0.5% of elements). A padded row leaking into a
+    real one would be an O(1) error, far outside it."""
     cfg, params, x, pos = _fused_case(0.625, jnp.float32)  # k = 20 of S = 32
     assert cfg.mod.capacity(x.shape[1]) % block_k != 0
     old = KFA.ROUTED_BLOCK_K
@@ -261,7 +267,7 @@ def test_fused_padding_tail(block_k):
     finally:
         KFA.ROUTED_BLOCK_K = old
     out_x = jax.jit(functools.partial(_run_backend, "xla", cfg, params))(x, pos)
-    np.testing.assert_array_equal(np.asarray(out_x), np.asarray(out_f))
+    np.testing.assert_allclose(np.asarray(out_x), np.asarray(out_f), rtol=1e-5, atol=1e-5)
 
 
 @pytest.mark.parametrize("ratio", RATIOS)
@@ -269,10 +275,13 @@ def test_fused_padding_tail(block_k):
 def test_fused_grad_matches(ratio, dtype):
     """Grad equivalence through both custom VJPs.
 
-    pallas_fused must be bit-for-bit equal to the pallas backend (both
-    route cotangents through kernel VJPs); vs xla's pure autodiff the
-    existing calibrated bounds apply (see test_execute_routed_grad_matches
-    — the fused backend must not be noisier than that baseline)."""
+    In f32, pallas_fused must match the pallas backend (both route
+    cotangents through kernel VJPs) to a tight f32 tolerance: XLA 0.9
+    fuses the two backward graphs differently, which drifts them by ulps
+    (measured <= 7.6e-6 absolute, 2.5e-6 relative), so bit equality no
+    longer holds. Vs xla's pure autodiff the existing calibrated bounds
+    apply (see test_execute_routed_grad_matches — the fused backend must
+    not be noisier than that baseline)."""
     cfg, params, x, pos = _fused_case(ratio, dtype, seed=4)
 
     def loss(backend, params, x):
@@ -287,7 +296,7 @@ def test_fused_grad_matches(ratio, dtype):
     gp, _ = ravel_pytree(grads["pallas"])
     gf, _ = ravel_pytree(grads["pallas_fused"])
     if dtype == jnp.float32:
-        np.testing.assert_array_equal(np.asarray(gp), np.asarray(gf))
+        np.testing.assert_allclose(np.asarray(gp), np.asarray(gf), rtol=1e-5, atol=1e-5)
         np.testing.assert_allclose(np.asarray(gx), np.asarray(gf), rtol=2e-5, atol=2e-6)
     else:
         # bf16: bound the fused↔xla spread by the pre-existing pallas↔xla
@@ -325,6 +334,24 @@ def test_fused_fallback_without_fused_fn():
     np.testing.assert_array_equal(
         np.asarray(outs["pallas"]), np.asarray(outs["pallas_fused"])
     )
+
+
+@pytest.mark.parametrize("backend", ["xla", "pallas", "pallas_fused"])
+def test_train_step_refuses_compiled_fused_kernels(backend, monkeypatch):
+    """Off the CPU the fused kernels would have to compile, which they
+    cannot: building a pallas_fused train step there fails with the clear
+    error before anything is traced; the other backends build."""
+    from repro.config import TrainConfig
+    from repro.kernels import ops
+    from repro.train.loop import make_train_step
+
+    cfg = with_mod_backend(tiny_cfg(), backend)
+    monkeypatch.setattr(ops, "on_cpu", lambda: False)
+    if backend == "pallas_fused":
+        with pytest.raises(ValueError, match="pallas_fused"):
+            make_train_step(cfg, TrainConfig())
+    else:
+        assert callable(make_train_step(cfg, TrainConfig()))
 
 
 @pytest.mark.parametrize("family", ["dense", "moe"])

@@ -36,6 +36,7 @@ from repro.distributed.sharding import (
     param_shardings,
     shard_ctx,
 )
+from repro.launch.mesh import make_mesh
 from repro.models import api
 from repro.models import blocks as BLK
 from tests.helpers import batch_for, tiny_cfg
@@ -49,7 +50,7 @@ needs8 = pytest.mark.skipif(
 
 
 def mesh42():
-    return jax.make_mesh((4, 2), ("data", "model"))
+    return make_mesh(MeshConfig(pod=1, data=4, model=2))
 
 
 def _place(params, batch, mesh, data=4, model=2):
@@ -143,11 +144,11 @@ def test_fused_dispatch_mesh_compat_gate():
         tiny_cfg(), mod=dataclasses.replace(tiny_cfg().mod, backend="pallas_fused")
     )
     assert BLK.fused_dispatch_supported(cfg)  # no mesh: unchanged
-    dp = shard_ctx(jax.make_mesh((1, 1), ("data", "model")))
+    dp = shard_ctx(make_mesh(MeshConfig(pod=1, data=1, model=1)))
     assert BLK.fused_dispatch_supported(cfg, dp)  # pure DP: fuses per shard
     if NDEV >= 2:
         # a >1 model axis splits the fused dims -> explicit fallback
-        tp = shard_ctx(jax.make_mesh((1, 2), ("data", "model")))
+        tp = shard_ctx(make_mesh(MeshConfig(pod=1, data=1, model=2)))
         assert not BLK.fused_dispatch_supported(cfg, tp)
     fsdp = dataclasses.replace(dp, fsdp=True)
     assert not BLK.fused_dispatch_supported(cfg, fsdp)
@@ -216,7 +217,7 @@ def test_forward_fused_dispatch_per_shard_pure_dp():
     per data shard inside shard_map; forward must match the single-device
     fused path (f32 kernels are bitwise — allow reduction-order slack for
     the surrounding ops)."""
-    mesh = jax.make_mesh((8, 1), ("data", "model"))
+    mesh = make_mesh(MeshConfig(pod=1, data=8, model=1))
     ctx = shard_ctx(mesh)
     cfg = tiny_cfg()
     cfg = dataclasses.replace(cfg, mod=dataclasses.replace(cfg.mod, backend="pallas_fused"))

@@ -13,14 +13,14 @@ from repro.distributed.sharding import (
     param_shardings,
 )
 from repro.launch.mesh import make_mesh
-from tests.helpers import abstract_mesh_compat
+from tests.helpers import auto_abstract_mesh
 
 
 def abstract_mesh(data=1, model=1, pod=1):
     # AbstractMesh: rule/pspec tests need mesh *shapes*, not devices
     if pod > 1:
-        return abstract_mesh_compat((pod, data, model), ("pod", "data", "model"))
-    return abstract_mesh_compat((data, model), ("data", "model"))
+        return auto_abstract_mesh((pod, data, model), ("pod", "data", "model"))
+    return auto_abstract_mesh((data, model), ("data", "model"))
 
 
 def small_mesh(fsdp=False):
