@@ -1,0 +1,10 @@
+import os
+import sys
+from pathlib import Path
+
+# the benchmark's tests run on the CPU, at tiny sizes
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+BENCH = Path(__file__).resolve().parents[1]
+for p in (str(BENCH.parent / "src"), str(BENCH), str(BENCH / "tests")):
+    if p not in sys.path:
+        sys.path.insert(0, p)
