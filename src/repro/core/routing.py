@@ -65,6 +65,7 @@ from jax.sharding import PartitionSpec as P
 from repro.config import ModelConfig
 from repro.core import router as R
 from repro.distributed.sharding import ShardCtx
+from repro.utils import scoped
 
 Params = Dict[str, jax.Array]
 Aux = Dict[str, jax.Array]
@@ -114,6 +115,7 @@ class RouteDecision(NamedTuple):
 # ---------------------------------------------------------------------------
 
 
+@scoped("mod.router")
 def decide_tokens(
     params: Params,
     x: jax.Array,  # (B, S, D)
@@ -163,6 +165,7 @@ def decide_tokens(
     return RouteDecision("token_topk", idx, gate, topk_mask, logits)
 
 
+@scoped("mod.router")
 def decide_tokens_ragged(
     params: Params,
     x: jax.Array,  # (1, T, D) flat token stream
@@ -239,20 +242,24 @@ def execute_routed_ragged(
     if backend in ("pallas", "pallas_fused"):
         from repro.kernels.ops import ragged_gather_rows_op, ragged_scatter_add_rows_op
 
-        x_sub = ragged_gather_rows_op(x[0], idx)
+        with jax.named_scope("mod.dispatch"):
+            x_sub = ragged_gather_rows_op(x[0], idx)
         delta, aux = block_delta_fn(x_sub, pos_sub)
-        out = ragged_scatter_add_rows_op(x[0], idx, delta, decision.gate)
+        with jax.named_scope("mod.dispatch"):
+            out = ragged_scatter_add_rows_op(x[0], idx, delta, decision.gate)
         return out[None], aux
-    xp = jnp.concatenate([x[0], jnp.zeros((1, x.shape[2]), x.dtype)])
-    x_sub = jnp.take(xp, jnp.where(idx >= 0, idx, T), axis=0)
+    with jax.named_scope("mod.dispatch"):
+        xp = jnp.concatenate([x[0], jnp.zeros((1, x.shape[2]), x.dtype)])
+        x_sub = jnp.take(xp, jnp.where(idx >= 0, idx, T), axis=0)
     delta, aux = block_delta_fn(x_sub, pos_sub)
-    update = (decision.gate[..., None] * delta.astype(jnp.float32)).astype(x.dtype)
-    k = idx.shape[1]
-    out = (
-        jnp.concatenate([x[0], jnp.zeros((1, x.shape[2]), x.dtype)])
-        .at[jnp.where(idx >= 0, idx, T).reshape(-1)]
-        .add(update.reshape(idx.shape[0] * k, -1))[:T]
-    )
+    with jax.named_scope("mod.dispatch"):
+        update = (decision.gate[..., None] * delta.astype(jnp.float32)).astype(x.dtype)
+        k = idx.shape[1]
+        out = (
+            jnp.concatenate([x[0], jnp.zeros((1, x.shape[2]), x.dtype)])
+            .at[jnp.where(idx >= 0, idx, T).reshape(-1)]
+            .add(update.reshape(idx.shape[0] * k, -1))[:T]
+        )
     return out[None], aux
 
 
@@ -315,6 +322,7 @@ def capacity_ladder(cfg: ModelConfig, scales) -> Tuple[ModelConfig, ...]:
     )
 
 
+@scoped("mod.router")
 def decide_batch(
     params: Params,
     x: jax.Array,  # (B, 1, D) — one decode token per sequence
@@ -374,6 +382,7 @@ def decide_batch(
 BACKENDS = ("xla", "pallas", "pallas_fused")
 
 
+@scoped("mod.dispatch")
 def _gather_tokens(x: jax.Array, idx: jax.Array, backend: str) -> jax.Array:
     # pallas_fused lands here only on its fallback path (no fused_block_fn):
     # the standalone pallas kernels are then the best available dispatch
@@ -386,6 +395,7 @@ def _gather_tokens(x: jax.Array, idx: jax.Array, backend: str) -> jax.Array:
     return jnp.take_along_axis(x, idx[..., None], axis=1)
 
 
+@scoped("mod.dispatch")
 def _scatter_add_tokens(
     x: jax.Array, idx: jax.Array, delta: jax.Array, gate: jax.Array, backend: str
 ) -> jax.Array:
@@ -400,6 +410,7 @@ def _scatter_add_tokens(
     return x.at[jnp.arange(B)[:, None], idx].add(update)
 
 
+@scoped("mod.dispatch")
 def gather_positions(positions: jax.Array, idx: jax.Array) -> jax.Array:
     """Token-axis position gather. positions: (B,S) or (3,B,S); idx: (B,k)."""
     if positions.ndim == 3:
@@ -407,6 +418,7 @@ def gather_positions(positions: jax.Array, idx: jax.Array) -> jax.Array:
     return jnp.take_along_axis(positions, idx, axis=1)
 
 
+@scoped("mod.dispatch")
 def _take_batch_positions(positions: jax.Array, idx: jax.Array) -> jax.Array:
     """Batch-axis position gather. positions: (B,1) or (3,B,1); idx: (kb,)."""
     if positions.ndim == 3:
@@ -460,11 +472,13 @@ def spmd_scatter_add_tokens(
     )(x, idx, delta, gate)
 
 
+@scoped("mod.dispatch")
 def gather_batch(decision: RouteDecision, tree):
     """Gather the routed sequences' slices of a cache pytree (decode)."""
     return jax.tree.map(lambda c: jnp.take(c, decision.idx, axis=0), tree)
 
 
+@scoped("mod.dispatch")
 def scatter_batch(decision: RouteDecision, tree, sub):
     """Write updated routed-sequence slices back into a cache pytree."""
     return jax.tree.map(lambda c, cs: c.at[decision.idx].set(cs), tree, sub)
@@ -521,11 +535,23 @@ def execute_routed(
         return out, aux
 
     assert decision.strategy == "batch_capacity", decision.strategy
-    x_sub = jnp.take(x, decision.idx, axis=0)
+    x_sub = _take_batch_rows(x, decision)
     pos_sub = None if positions is None else _take_batch_positions(positions, decision.idx)
     delta, aux = block_delta_fn(x_sub, pos_sub)
+    return _add_batch_rows(x, decision, delta), aux
+
+
+@scoped("mod.dispatch")
+def _take_batch_rows(x: jax.Array, decision: RouteDecision) -> jax.Array:
+    """The routed rows of a (B, 1, D) decode stream (batch_capacity)."""
+    return jnp.take(x, decision.idx, axis=0)
+
+
+@scoped("mod.dispatch")
+def _add_batch_rows(x: jax.Array, decision: RouteDecision, delta: jax.Array) -> jax.Array:
+    """Gated scatter-add of the routed rows' block output (batch_capacity)."""
     update = (decision.gate[:, None, None] * delta.astype(jnp.float32)).astype(x.dtype)
-    return x.at[decision.idx].add(update), aux
+    return x.at[decision.idx].add(update)
 
 
 def _spmd_fused(
@@ -571,6 +597,7 @@ def _spmd_fused(
 # ---------------------------------------------------------------------------
 
 
+@scoped("mod.router")
 def routing_aux(
     decision: RouteDecision, params: Params, x: jax.Array, cfg: ModelConfig
 ) -> Aux:
@@ -661,13 +688,12 @@ def _exec_batch_capacity(
     than two implementations happening to agree."""
     caches_sub = gather_batch(decision, caches)
     delta, new_caches_sub, inner = block_fn(
-        jnp.take(x, decision.idx, axis=0),
+        _take_batch_rows(x, decision),
         None if positions is None else _take_batch_positions(positions, decision.idx),
         caches_sub,
         decision,
     )
-    update = (decision.gate[:, None, None] * delta.astype(jnp.float32)).astype(x.dtype)
-    out = x.at[decision.idx].add(update)
+    out = _add_batch_rows(x, decision, delta)
     return out, scatter_batch(decision, caches, new_caches_sub), inner
 
 

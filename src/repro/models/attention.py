@@ -26,6 +26,7 @@ import numpy as np
 
 from repro.config import ModelConfig
 from repro.models.layers import _dense_init, apply_mrope, apply_rope
+from repro.utils import scoped
 
 Params = Dict[str, jax.Array]
 
@@ -346,6 +347,7 @@ def _t_pos(pos: jax.Array) -> jax.Array:
     return pos[0] if pos.ndim == 3 else pos
 
 
+@scoped("attention")
 def self_attention(
     params: Params,
     x: jax.Array,
@@ -359,6 +361,7 @@ def self_attention(
     return attend_auto(q, k, v, tp, tp, cfg) @ params["wo"]
 
 
+@scoped("attention")
 def routed_self_attention(
     params: Params,
     ln1: Params,  # the block's pre-attention RMSNorm params
@@ -394,6 +397,7 @@ def routed_self_attention(
     )
 
 
+@scoped("attention")
 def ragged_self_attention(
     params: Params,
     x: jax.Array,  # (1, T, D) flat token stream
@@ -419,6 +423,7 @@ def ragged_self_attention(
     return attend(q, k, v, mask, cfg) @ params["wo"]
 
 
+@scoped("attention")
 def cross_attention(
     params: Params,
     x: jax.Array,
@@ -500,6 +505,7 @@ def cache_write(
     return {"k": k, "v": v, "pos": pos, "cursor": cursor}
 
 
+@scoped("attention")
 def decode_attention(
     params: Params,
     x: jax.Array,  # (B, 1, D)
@@ -536,6 +542,7 @@ def decode_attention(
     return out, cache
 
 
+@scoped("attention")
 def chunk_self_attention(
     params: Params,
     x: jax.Array,  # (B, C, D) one prefill chunk
@@ -564,6 +571,7 @@ def chunk_self_attention(
     return out @ params["wo"], cache
 
 
+@scoped("attention")
 def prefill_self_attention(
     params: Params,
     x: jax.Array,

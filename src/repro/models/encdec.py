@@ -27,9 +27,9 @@ from repro.models.layers import (
     init_embedding,
     init_mlp,
     init_rmsnorm,
+    lm_head,
     mlp,
     rmsnorm,
-    unembed,
 )
 
 Params = Dict[str, Any]
@@ -160,8 +160,7 @@ def forward(
     aux = jax.tree.map(jnp.mean, aux_stack)
     if last_only:
         x = x[:, -1:]
-    x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
-    return unembed(params["embed"], x), aux
+    return lm_head(params, x, cfg), aux
 
 
 # ---------------------------------------------------------------------------
@@ -279,6 +278,5 @@ def decode_step(
     x, (new_groups, aux_stack) = scan_or_loop(body, x, (params["groups"], caches["groups"]), unroll=cfg.unroll_layers)
     # mean over the layer-group axis only (per-sequence telemetry keeps (B,))
     aux = jax.tree.map(lambda a: jnp.mean(a, axis=0), aux_stack)
-    x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
-    logits = unembed(params["embed"], x)[:, 0]
+    logits = lm_head(params, x, cfg)[:, 0]
     return logits, {"groups": new_groups}, aux

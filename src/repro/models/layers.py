@@ -12,6 +12,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.config import ModelConfig
+from repro.utils import scoped
 
 Params = Dict[str, jax.Array]
 
@@ -110,6 +111,7 @@ def init_mlp(key, cfg: ModelConfig, d_ff: Optional[int] = None) -> Params:
     return p
 
 
+@scoped("mlp")
 def mlp(params: Params, x: jax.Array, cfg: ModelConfig) -> jax.Array:
     act = jax.nn.silu if cfg.act == "silu" else jax.nn.gelu
     up = x @ params["w_up"]
@@ -145,6 +147,12 @@ def unembed(params: Params, x: jax.Array) -> jax.Array:
     if "unemb" in params:
         return x @ params["unemb"]
     return x @ params["tok"].T
+
+
+@scoped("lm_head")
+def lm_head(params: Params, x: jax.Array, cfg: ModelConfig) -> jax.Array:
+    """Final norm and unembedding: the logits of the stream ``x``."""
+    return unembed(params["embed"], rmsnorm(params["final_norm"], x, cfg.norm_eps))
 
 
 def cross_entropy(

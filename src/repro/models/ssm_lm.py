@@ -31,8 +31,8 @@ from repro.models.layers import (
     embed,
     init_embedding,
     init_rmsnorm,
+    lm_head,
     rmsnorm,
-    unembed,
 )
 
 Params = Dict[str, Any]
@@ -139,8 +139,7 @@ def forward(
     aux = jax.tree.map(jnp.mean, aux_stack)
     if last_only:
         x = x[:, -1:]
-    x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
-    return unembed(params["embed"], x), aux
+    return lm_head(params, x, cfg), aux
 
 
 def make_cache(cfg: ModelConfig, batch: int, ctx: int, specs: bool = False) -> Params:
@@ -197,8 +196,7 @@ def decode_step(
     x, (new_caches, aux_stack) = scan_or_loop(body, x, (params["groups"], caches["groups"]), unroll=cfg.unroll_layers)
     # mean over the layer-group axis only (per-sequence telemetry keeps (B,))
     aux = jax.tree.map(lambda a: jnp.mean(a, axis=0), aux_stack)
-    x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
-    logits = unembed(params["embed"], x)[:, 0]
+    logits = lm_head(params, x, cfg)[:, 0]
     return logits, {"groups": new_caches}, aux
 
 
@@ -301,8 +299,7 @@ def forward_hybrid(
     aux = jax.tree.map(jnp.mean, aux_stack)
     if last_only:
         x = x[:, -1:]
-    x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
-    return unembed(params["embed"], x), aux
+    return lm_head(params, x, cfg), aux
 
 
 def make_hybrid_cache(cfg: ModelConfig, batch: int, ctx: int, specs: bool = False) -> Params:
@@ -372,6 +369,5 @@ def decode_step_hybrid(
         outer_body, x, (params["groups"], caches["groups"], caches["attn"])
     )
     aux = jax.tree.map(lambda a: jnp.mean(a, axis=0), aux_stack)
-    x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
-    logits = unembed(params["embed"], x)[:, 0]
+    logits = lm_head(params, x, cfg)[:, 0]
     return logits, {"attn": new_attn, "groups": new_groups}, aux

@@ -31,8 +31,7 @@ from repro.models.layers import (
     embed,
     init_embedding,
     init_rmsnorm,
-    rmsnorm,
-    unembed,
+    lm_head,
 )
 
 Params = Dict[str, Any]
@@ -160,8 +159,7 @@ def forward(
         aux.update(_prefix("tail", a))
     if last_only:
         x = x[:, -1:]
-    x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
-    logits = unembed(params["embed"], x)
+    logits = lm_head(params, x, cfg)
     return logits, aux
 
 
@@ -228,8 +226,7 @@ def forward_ragged(
     if "tail" in params:
         x, a = BLK.block_apply_ragged(params["tail"], x, positions, seg_id, cfg)
         aux.update(_prefix("tail", a))
-    x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
-    logits = unembed(params["embed"], x)
+    logits = lm_head(params, x, cfg)
     return logits[0], aux
 
 
@@ -315,7 +312,8 @@ def _mod_prefill_group(gp, h, positions, cache, cfg):
 
     h, aux = ROUT.execute_routed(decision, h, delta_fn, cfg, positions)
     aux = dict(aux)
-    aux["mod/router_bce"] = R.router_aux_loss(decision.logits, decision.mask)
+    with jax.named_scope("mod.router"):
+        aux["mod/router_bce"] = R.router_aux_loss(decision.logits, decision.mask)
     return h, filled["cache"], aux, (decision.logits, decision.mask)
 
 
@@ -353,8 +351,7 @@ def prefill(
     if "tail" in params:
         x, c, _ = BLK.block_prefill(params["tail"], x, positions, caches["tail"], cfg)
         out_caches["tail"] = c
-    x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
-    logits = unembed(params["embed"], x)
+    logits = lm_head(params, x, cfg)
     return logits, out_caches
 
 
@@ -375,13 +372,14 @@ def _mod_chunk_group(gp, h, positions, cache, cfg):
     in exchange for a fixed per-step prefill footprint.
     """
     k_cap = cfg.mod.capacity(h.shape[1])
-    logits = R.router_logits(gp["router"], h)
-    valid = positions >= 0
-    idx, gate_logits, mask = R.mod_select(
-        jnp.where(valid, logits, -jnp.inf), k_cap, cfg.mod, None
-    )
-    gate = R.apply_gate(gate_logits, cfg.mod)
-    gate = jnp.where(jnp.take_along_axis(valid, idx, axis=1), gate, 0.0)
+    with jax.named_scope("mod.router"):
+        logits = R.router_logits(gp["router"], h)
+        valid = positions >= 0
+        idx, gate_logits, mask = R.mod_select(
+            jnp.where(valid, logits, -jnp.inf), k_cap, cfg.mod, None
+        )
+        gate = R.apply_gate(gate_logits, cfg.mod)
+        gate = jnp.where(jnp.take_along_axis(valid, idx, axis=1), gate, 0.0)
     decision = ROUT.RouteDecision("token_topk", idx, gate, mask, logits)
     filled = {}
 
@@ -441,8 +439,7 @@ def prefill_chunk(
         out_caches["tail"] = c
     last = jnp.clip(n_valid - 1, 0, C - 1)
     x = jax.lax.dynamic_slice_in_dim(x, last, 1, axis=1)  # (B, 1, D)
-    x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
-    logits = unembed(params["embed"], x)[:, 0]
+    logits = lm_head(params, x, cfg)[:, 0]
     return logits, out_caches
 
 
@@ -508,6 +505,5 @@ def decode_step(
     if "tail" in params:
         x, c, _ = BLK.block_decode(params["tail"], x, positions, caches["tail"], cfg)
         out_caches["tail"] = c
-    x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
-    logits = unembed(params["embed"], x)[:, 0]
+    logits = lm_head(params, x, cfg)[:, 0]
     return logits, out_caches, aux

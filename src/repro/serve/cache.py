@@ -50,6 +50,7 @@ import numpy as np
 from repro.config import ModelConfig
 from repro.models import api
 from repro.serve.quant import QuantConfig, leaf_groups, quantize_rows
+from repro.utils import scoped
 
 
 def _batch_axes(cfg: ModelConfig, batch: int, ctx: int):
@@ -243,6 +244,7 @@ def _qmap(spec: PoolSpec, scales) -> Dict[int, int]:
     return {j: m for m, j in enumerate(spec.quant_ids)}
 
 
+@scoped("paged.materialize")
 def paged_materialize_q(
     spec: PoolSpec,
     pages: List[jax.Array],
@@ -281,6 +283,7 @@ def paged_materialize(
     return paged_materialize_q(spec, pages, [], resid, table)
 
 
+@scoped("paged.writeback")
 def paged_writeback_q(
     spec: PoolSpec,
     new_caches: Any,
@@ -364,6 +367,7 @@ def slot_update(spec: PoolSpec, caches: Any, sub: Any, slot: jax.Array) -> Any:
     return jax.tree_util.tree_unflatten(spec.treedef, out)
 
 
+@scoped("paged.writeback")
 def paged_writeback_tokens_q(
     spec: PoolSpec,
     new_caches: Any,
@@ -459,6 +463,7 @@ def quant_roundtrip(spec: PoolSpec, caches: Any, mask: jax.Array) -> Any:
     return jax.tree_util.tree_unflatten(spec.treedef, leaves)
 
 
+@scoped("paged.writeback")
 def paged_collect_rows(spec: "PoolSpec", caches: Any, pos: jax.Array) -> List[jax.Array]:
     """Extract each slot's KV row at ``pos[b]`` from a logical cache pytree
     (one row per paged leaf, per slot). The speculative verify scan calls
@@ -479,6 +484,7 @@ def paged_collect_rows(spec: "PoolSpec", caches: Any, pos: jax.Array) -> List[ja
     return rows
 
 
+@scoped("paged.writeback")
 def paged_scatter_rows_q(
     spec: "PoolSpec",
     rows: List[jax.Array],  # per paged leaf: lead + (W,) + tail row stacks
@@ -816,6 +822,7 @@ class PagedCachePool:
         self.prefix_lookup_tokens = 0
         self.prefix_evictions = 0
         self.peak_pages_in_use = 0
+        self.scrubbed_pages = 0  # pages zeroed on (re)mapping
 
         (self._reset_resid_fn, self._write_fn, self._scrub_fn,
          self._read_fn) = _pool_ops(cfg, batch_size, ctx, page_size, backend,
@@ -1014,6 +1021,7 @@ class PagedCachePool:
                 if new_ids:
                     self.pages, self.scales = self._scrub_fn(
                     self.pages, self.scales, self._pad_ids(new_ids))
+                    self.scrubbed_pages += len(new_ids)
                     # partial maps still raise in_use: peak must see them
                     self.peak_pages_in_use = max(
                         self.peak_pages_in_use,
@@ -1028,6 +1036,7 @@ class PagedCachePool:
         if new_ids:
             self.pages, self.scales = self._scrub_fn(
                     self.pages, self.scales, self._pad_ids(new_ids))
+            self.scrubbed_pages += len(new_ids)
         self.peak_pages_in_use = max(
             self.peak_pages_in_use, int(np.sum(self.ref[_RESERVED:] > 0))
         )

@@ -123,6 +123,12 @@ _BATCH_PREFILL_FAMILIES = ("dense", "moe")
 _JIT_CACHE: "OrderedDict[Any, Callable]" = OrderedDict()
 _JIT_CACHE_MAX = 32
 
+# Host spans of one engine step, on the profiler's clock beside the device's
+# ops (``serve.admit``, ``serve.prefill``, ``serve.pages``, ``serve.decode``,
+# ``serve.logits_to_host``, ``serve.sample``, ``serve.invariants``). Outside
+# a trace each costs about a microsecond.
+_span = jax.profiler.TraceAnnotation
+
 
 def _cached_jit(kind: str, key: Any, make: Callable[[], Callable]) -> Callable:
     from repro.serve.cache import lru_cached
@@ -147,6 +153,15 @@ def _warn_legacy_kwargs() -> None:
             DeprecationWarning,
             stacklevel=3,
         )
+
+
+def _decode_aux_to_host(aux: Dict[str, Any]):
+    """A decode step's routing telemetry on the host: per-row routed shares,
+    per-row scores and the routed fraction, each None where the step
+    reports none."""
+    return tuple(None if aux.get(k) is None else np.asarray(aux[k])
+                 for k in ("mod/decode_routed", "mod/decode_scores",
+                           "mod/decode_routed_frac"))
 
 
 class _PoolExhausted(RuntimeError):
@@ -1009,20 +1024,8 @@ class ServingEngine:
                 self.pool.write_slot(slot.idx, sub)
             if self._batch_prefill:
                 try:
-                    if self._prefill_chunk is not None:
-                        logits_row = self._chunked_prefill(slot, req)
-                    else:
-                        logits, sub = self._prefill_fn(
-                            self.params, jnp.asarray(req.tokens)[None]
-                        )
-                        if self._paged and not self.pool.alloc_pages(
-                            slot.idx, req.prompt_len
-                        ):
-                            raise _PoolExhausted
-                        self.pool.write_slot(slot.idx, sub)
-                        logits_row = np.asarray(logits[0, -1])
-                        self._prefill_tokens_computed += req.prompt_len
-                        self._positions_computed += req.prompt_len
+                    with _span("serve.prefill", uid=req.uid, tokens=req.prompt_len):
+                        logits_row = self._prefill(slot, req)
                 except _PoolExhausted:
                     self._abort_admission(slot, req)
                     continue
@@ -1065,6 +1068,19 @@ class ServingEngine:
         self.scheduler.requeue(req)
         # not a preemption — the request never entered the decode batch
         self.admission_aborts += 1
+
+    def _prefill(self, slot: Slot, req: Request) -> np.ndarray:
+        """Batch prefill of an admitted request: chunked, or the prompt in
+        one call; returns the last-position logits row."""
+        if self._prefill_chunk is not None:
+            return self._chunked_prefill(slot, req)
+        logits, sub = self._prefill_fn(self.params, jnp.asarray(req.tokens)[None])
+        if self._paged and not self.pool.alloc_pages(slot.idx, req.prompt_len):
+            raise _PoolExhausted
+        self.pool.write_slot(slot.idx, sub)
+        self._prefill_tokens_computed += req.prompt_len
+        self._positions_computed += req.prompt_len
+        return np.asarray(logits[0, -1])
 
     def _chunked_prefill(self, slot: Slot, req: Request) -> np.ndarray:
         """Ingest the prompt in fixed ``prefill_chunk`` pieces against the
@@ -1285,10 +1301,24 @@ class ServingEngine:
         """Shared tail of every step path: wall-clock accounting plus one
         controller observation (queue depth + this step's latency) per
         engine step."""
-        dt = time.time() - t0
+        dt = time.perf_counter() - t0
         self._wall_s += dt
         if self._controller is not None:
             self._controller.observe(len(self.scheduler.queue), dt)
+
+    def _check_invariants(self) -> None:
+        with _span("serve.invariants"):
+            self.scheduler.check_invariants(self.slots, len(self.finished))
+
+    def _map_pages(self, plan: Callable[[], Any]) -> Any:
+        """Run a page-mapping pass (lazy growth or the mixed step's segment
+        plan) inside a ``serve.pages`` span that records the pages it
+        scrubbed."""
+        with _span("serve.pages") as span:
+            before = self.pool.scrubbed_pages
+            out = plan()
+            span.set_metadata(scrubbed=self.pool.scrubbed_pages - before)
+        return out
 
     def _preempt(self, slot: Slot) -> None:
         """Page-pool OOM backstop: evict the youngest-admitted slot back to
@@ -1403,46 +1433,50 @@ class ServingEngine:
         if self._ragged:
             return self._step_ragged()
         done_before = len(self.finished)
-        t0 = time.time()
+        t0 = time.perf_counter()
         self._step_prologue()
-        self._admit()
+        with _span("serve.admit"):
+            self._admit()
         if self._paged:
-            self._grow_pages()  # may preempt; must precede the active scan
+            self._map_pages(self._grow_pages)  # may preempt; precedes the active scan
         active_slots = [s for s in self.slots if s.active]
         if not active_slots:
             self.last_step_level = 0  # no decode ran: nothing was degraded
             self.step_count += 1
             self._step_epilogue(t0)
-            self.scheduler.check_invariants(self.slots, len(self.finished))
+            self._check_invariants()
             return self.finished[done_before:]
 
         B = self.batch_size
-        tokens = np.zeros((B, 1), np.int32)
-        pos = np.zeros((B,), np.int32)
-        active = np.zeros((B,), bool)
-        for s in active_slots:
-            tokens[s.idx, 0] = s.next_token
-            pos[s.idx] = s.pos
-            active[s.idx] = True
+        with _span("serve.decode", step=self.step_count, live=len(active_slots)):
+            tokens = np.zeros((B, 1), np.int32)
+            pos = np.zeros((B,), np.int32)
+            active = np.zeros((B,), bool)
+            for s in active_slots:
+                tokens[s.idx, 0] = s.next_token
+                pos[s.idx] = s.pos
+                active[s.idx] = True
 
-        lvl = self._capacity_level()
-        self.last_step_level = lvl  # which ladder level priced this step
-        step_fn = self._level_fn(lvl) if lvl else self._step_fn
-        if lvl:
-            self._degraded_decode_steps += 1
-        if self._paged:
-            (logits, self.pool.pages, self.pool.resid, self.pool.scales,
-             aux) = step_fn(
-                self.params, self.pool.pages, self.pool.scales,
-                self.pool.resid, self.pool.device_table(),
-                jnp.asarray(tokens), jnp.asarray(pos), jnp.asarray(active),
-            )
-        else:
-            logits, self.pool.caches, aux = step_fn(
-                self.params, self.pool.caches, self._place(tokens),
-                self._place(pos), self._place(active),
-            )
-        logits_np = np.asarray(logits)
+            lvl = self._capacity_level()
+            self.last_step_level = lvl  # which ladder level priced this step
+            step_fn = self._level_fn(lvl) if lvl else self._step_fn
+            if lvl:
+                self._degraded_decode_steps += 1
+            if self._paged:
+                (logits, self.pool.pages, self.pool.resid, self.pool.scales,
+                 aux) = step_fn(
+                    self.params, self.pool.pages, self.pool.scales,
+                    self.pool.resid, self.pool.device_table(),
+                    jnp.asarray(tokens), jnp.asarray(pos), jnp.asarray(active),
+                )
+            else:
+                logits, self.pool.caches, aux = step_fn(
+                    self.params, self.pool.caches, self._place(tokens),
+                    self._place(pos), self._place(active),
+                )
+        with _span("serve.logits_to_host"):
+            logits_np = np.asarray(logits)
+            routed_np, scores_np, frac_np = _decode_aux_to_host(aux)
         if self._logit_tap is not None and active_slots:
             self._logit_tap(logits_np)
         if self._faults is not None:
@@ -1450,15 +1484,23 @@ class ServingEngine:
         self._positions_computed += B
         self._positions_wasted += B - len(active_slots)
 
-        routed = aux.get("mod/decode_routed")
-        scores = aux.get("mod/decode_scores")
-        routed_np = None if routed is None else np.asarray(routed)
-        scores_np = None if scores is None else np.asarray(scores)
-        if "mod/decode_routed_frac" in aux:
-            self._routed_frac_sum += float(aux["mod/decode_routed_frac"])
+        if frac_np is not None:
+            self._routed_frac_sum += float(frac_np)
             self._routed_frac_steps += 1
         self._occupancy_sum += len(active_slots)
+        with _span("serve.sample"):
+            self._sample_decoded(active_slots, logits_np, routed_np, scores_np)
 
+        self.step_count += 1
+        self._step_epilogue(t0)
+        self._check_invariants()
+        return self.finished[done_before:]
+
+    def _sample_decoded(self, active_slots: List[Slot], logits_np: np.ndarray,
+                        routed_np: Optional[np.ndarray],
+                        scores_np: Optional[np.ndarray]) -> None:
+        """Per-slot host update after a decode step: finiteness police,
+        routing telemetry, prompt ingestion or sampling."""
         for s in active_slots:
             if not np.isfinite(logits_np[s.idx]).all():
                 # finiteness police: a poisoned row fails only its own
@@ -1496,11 +1538,6 @@ class ServingEngine:
                 if s.req is not None:
                     s.next_token = tok
 
-        self.step_count += 1
-        self._step_epilogue(t0)
-        self.scheduler.check_invariants(self.slots, len(self.finished))
-        return self.finished[done_before:]
-
     def _step_ragged(self, admit: bool = True) -> List[RequestOutput]:
         """One mixed prefill+decode step: admit by token budget, plan the
         prefill segment grid, run the single jitted step, then advance
@@ -1511,63 +1548,67 @@ class ServingEngine:
         ``admit=False``: the speculative path already admitted this step
         and fell back here because prompts are still draining."""
         done_before = len(self.finished)
-        t0 = time.time()
+        t0 = time.perf_counter()
         if admit:
             # admit=False means the speculative path already ran the
             # prologue (police + faults) and admission for this step
             self._step_prologue()
-            self._admit_ragged()
-        segs = self._plan_segments()  # maps pages; may preempt mid-prefill
+            with _span("serve.admit"):
+                self._admit_ragged()
+        segs = self._map_pages(self._plan_segments)  # may preempt mid-prefill
         active_slots = [s for s in self.slots if s.active]
         if not active_slots:
             self.last_step_level = 0  # no decode ran: nothing was degraded
             self.step_count += 1
             self._step_epilogue(t0)
-            self.scheduler.check_invariants(self.slots, len(self.finished))
+            self._check_invariants()
             return self.finished[done_before:]
 
         B = self.batch_size
         C = self._prefill_chunk
         S = self._ragged_segments
-        dec_tokens = np.zeros((B, 1), np.int32)
-        dec_pos = np.zeros((B,), np.int32)
-        dec_act = np.zeros((B,), bool)
         decode_slots = [s for s in self.slots if s.state == GENERATE]
-        for s in decode_slots:
-            dec_tokens[s.idx, 0] = s.next_token
-            dec_pos[s.idx] = s.pos
-            dec_act[s.idx] = True
+        with _span("serve.decode", step=self.step_count, live=len(active_slots)):
+            dec_tokens = np.zeros((B, 1), np.int32)
+            dec_pos = np.zeros((B,), np.int32)
+            dec_act = np.zeros((B,), bool)
+            for s in decode_slots:
+                dec_tokens[s.idx, 0] = s.next_token
+                dec_pos[s.idx] = s.pos
+                dec_act[s.idx] = True
 
-        # dead segments (slot 0, len 0) are exact cache no-ops in-step
-        pf_tokens = np.zeros((S * C,), np.int32)
-        seg_slot = np.zeros((S,), np.int32)
-        seg_start = np.zeros((S,), np.int32)
-        seg_len = np.zeros((S,), np.int32)
-        seg_off = np.zeros((S,), np.int32)
-        for k, (s, start, nv) in enumerate(segs):
-            seg_slot[k] = s.idx
-            seg_start[k] = start
-            seg_len[k] = nv
-            seg_off[k] = k * C
-            pf_tokens[k * C : k * C + nv] = np.asarray(
-                s.req.tokens[start : start + nv]
+            # dead segments (slot 0, len 0) are exact cache no-ops in-step
+            pf_tokens = np.zeros((S * C,), np.int32)
+            seg_slot = np.zeros((S,), np.int32)
+            seg_start = np.zeros((S,), np.int32)
+            seg_len = np.zeros((S,), np.int32)
+            seg_off = np.zeros((S,), np.int32)
+            for k, (s, start, nv) in enumerate(segs):
+                seg_slot[k] = s.idx
+                seg_start[k] = start
+                seg_len[k] = nv
+                seg_off[k] = k * C
+                pf_tokens[k * C : k * C + nv] = np.asarray(
+                    s.req.tokens[start : start + nv]
+                )
+
+            lvl = self._capacity_level()
+            self.last_step_level = lvl  # which ladder level priced this step
+            step_fn = self._level_fn(lvl) if lvl else self._step_fn
+            if lvl:
+                self._degraded_decode_steps += 1
+            (logits, seg_logits, seg_resid, self.pool.pages, self.pool.resid,
+             self.pool.scales, aux) = step_fn(
+                self.params, self.pool.pages, self.pool.scales, self.pool.resid,
+                self.pool.device_table(),
+                jnp.asarray(dec_tokens), jnp.asarray(dec_pos), jnp.asarray(dec_act),
+                jnp.asarray(pf_tokens), jnp.asarray(seg_slot),
+                jnp.asarray(seg_start), jnp.asarray(seg_len), jnp.asarray(seg_off),
             )
-
-        lvl = self._capacity_level()
-        self.last_step_level = lvl  # which ladder level priced this step
-        step_fn = self._level_fn(lvl) if lvl else self._step_fn
-        if lvl:
-            self._degraded_decode_steps += 1
-        (logits, seg_logits, seg_resid, self.pool.pages, self.pool.resid,
-         self.pool.scales, aux) = step_fn(
-            self.params, self.pool.pages, self.pool.scales, self.pool.resid,
-            self.pool.device_table(),
-            jnp.asarray(dec_tokens), jnp.asarray(dec_pos), jnp.asarray(dec_act),
-            jnp.asarray(pf_tokens), jnp.asarray(seg_slot),
-            jnp.asarray(seg_start), jnp.asarray(seg_len), jnp.asarray(seg_off),
-        )
-        logits_np = np.asarray(logits)
-        seg_logits_np = np.asarray(seg_logits)
+        with _span("serve.logits_to_host"):
+            logits_np = np.asarray(logits)
+            seg_logits_np = np.asarray(seg_logits)
+            routed_np, scores_np, frac_np = _decode_aux_to_host(aux)
         if self._logit_tap is not None and decode_slots:
             self._logit_tap(logits_np)
         if self._faults is not None:
@@ -1581,12 +1622,8 @@ class ServingEngine:
         self._positions_wasted += (len(segs) * C - n_pf) + (B - len(decode_slots))
         self._occupancy_sum += len(active_slots)
 
-        routed = aux.get("mod/decode_routed")
-        scores = aux.get("mod/decode_scores")
-        routed_np = None if routed is None else np.asarray(routed)
-        scores_np = None if scores is None else np.asarray(scores)
-        if decode_slots and "mod/decode_routed_frac" in aux:
-            self._routed_frac_sum += float(aux["mod/decode_routed_frac"])
+        if decode_slots and frac_np is not None:
+            self._routed_frac_sum += float(frac_np)
             self._routed_frac_steps += 1
 
         # prefill slots: advance prompt progress, register every chunk
@@ -1609,49 +1646,31 @@ class ServingEngine:
                     self.pool.prefix_register(
                         s.idx, np.asarray(s.req.tokens), {end: snap}
                     )
-        for s in [t for t in self.slots if t.state == PREFILL]:
-            if s.idx not in last_seg:
-                continue  # over budget this step; waits for the next
-            if s.prompt_idx >= s.req.prompt_len:
-                row = seg_logits_np[last_seg[s.idx]]
-                if not np.isfinite(row).all():
-                    self._finish(
-                        s, FINISH_ERROR,
-                        error="non-finite prefill-segment logits at step "
-                              f"{self.step_count}",
-                    )
-                    continue
-                tok = self._sample(s.req, row, 0)
-                self._push_token(s, tok)
-                if s.req is not None:
-                    s.state = GENERATE
-                    s.next_token = tok
-
-        for s in decode_slots:
-            if not np.isfinite(logits_np[s.idx]).all():
-                # poisoned decode row: fail only this request (rows are
-                # independent — see step())
-                self._finish(
-                    s, FINISH_ERROR,
-                    error=f"non-finite logits at step {self.step_count}",
-                )
-                continue
-            if routed_np is not None:
-                s.routed_sum += float(routed_np[s.idx])
-                s.routed_steps += 1
-            if scores_np is not None:
-                s.score = float(scores_np[s.idx])
-                s.score_sum += s.score
-                s.score_steps += 1
-            s.pos += 1
-            tok = self._sample(s.req, logits_np[s.idx], len(s.generated))
-            self._push_token(s, tok)
-            if s.req is not None:
-                s.next_token = tok
+        with _span("serve.sample"):
+            for s in [t for t in self.slots if t.state == PREFILL]:
+                if s.idx not in last_seg:
+                    continue  # over budget this step; waits for the next
+                if s.prompt_idx >= s.req.prompt_len:
+                    row = seg_logits_np[last_seg[s.idx]]
+                    if not np.isfinite(row).all():
+                        self._finish(
+                            s, FINISH_ERROR,
+                            error="non-finite prefill-segment logits at step "
+                                  f"{self.step_count}",
+                        )
+                        continue
+                    tok = self._sample(s.req, row, 0)
+                    self._push_token(s, tok)
+                    if s.req is not None:
+                        s.state = GENERATE
+                        s.next_token = tok
+            # decode rows: the padded step's per-slot update (poisoned rows
+            # fail only their own request — rows are independent)
+            self._sample_decoded(decode_slots, logits_np, routed_np, scores_np)
 
         self.step_count += 1
         self._step_epilogue(t0)
-        self.scheduler.check_invariants(self.slots, len(self.finished))
+        self._check_invariants()
         return self.finished[done_before:]
 
     def _step_speculative(self) -> List[RequestOutput]:
@@ -1674,63 +1693,70 @@ class ServingEngine:
         falls back to the normal mixed step while any prompt is still
         draining; speculation only covers pure-decode steps."""
         done_before = len(self.finished)
-        t0 = time.time()
+        t0 = time.perf_counter()
         self._step_prologue()
         n = self._speculate
         cap = self.scheduler.speculative_admission_cap(
             sum(1 for s in self.slots if s.active), n + 1
         )
         if self._ragged:
-            self._admit_ragged(max_admissions=cap)
+            with _span("serve.admit"):
+                self._admit_ragged(max_admissions=cap)
             if any(s.state == PREFILL for s in self.slots):
-                self._wall_s += time.time() - t0
+                self._wall_s += time.perf_counter() - t0
                 return self._step_ragged(admit=False)
         else:
-            self._admit(max_admissions=cap)
+            with _span("serve.admit"):
+                self._admit(max_admissions=cap)
         # every verify position this round writes a KV row: map the whole
         # window's pages up front (capped at each slot's own budget)
-        self._grow_pages(lookahead=n + 1)
+        self._map_pages(lambda: self._grow_pages(lookahead=n + 1))
         active_slots = [s for s in self.slots if s.active]
         if not active_slots:
             self.last_step_level = 0  # no decode ran: nothing was degraded
             self.step_count += 1
             self._step_epilogue(t0)
-            self.scheduler.check_invariants(self.slots, len(self.finished))
+            self._check_invariants()
             return self.finished[done_before:]
 
         B = self.batch_size
-        tokens = np.zeros((B, 1), np.int32)
-        pos = np.zeros((B,), np.int32)
-        active = np.zeros((B,), bool)
-        limit = np.zeros((B,), np.int32)
-        for s in active_slots:
-            tokens[s.idx, 0] = s.next_token
-            pos[s.idx] = s.pos
-            active[s.idx] = True
-            limit[s.idx] = min(s.req.total_len, self.ctx)
+        with _span("serve.decode", step=self.step_count, live=len(active_slots)):
+            tokens = np.zeros((B, 1), np.int32)
+            pos = np.zeros((B,), np.int32)
+            active = np.zeros((B,), bool)
+            limit = np.zeros((B,), np.int32)
+            for s in active_slots:
+                tokens[s.idx, 0] = s.next_token
+                pos[s.idx] = s.pos
+                active[s.idx] = True
+                limit[s.idx] = min(s.req.total_len, self.ctx)
 
-        (drafts, logits, resids, self.pool.pages, self.pool.scales,
-         aux) = self._spec_fn(
-            self.params, self.pool.pages, self.pool.scales, self.pool.resid,
-            self.pool.device_table(), jnp.asarray(tokens),
-            jnp.asarray(pos), jnp.asarray(active), jnp.asarray(limit),
-        )
-        drafts_np = np.asarray(drafts)  # (n, B)
-        logits_np = np.asarray(logits)  # (n+1, B, V)
+            (drafts, logits, resids, self.pool.pages, self.pool.scales,
+             aux) = self._spec_fn(
+                self.params, self.pool.pages, self.pool.scales, self.pool.resid,
+                self.pool.device_table(), jnp.asarray(tokens),
+                jnp.asarray(pos), jnp.asarray(active), jnp.asarray(limit),
+            )
+        with _span("serve.logits_to_host"):
+            drafts_np = np.asarray(drafts)  # (n, B)
+            logits_np = np.asarray(logits)  # (n+1, B, V)
+            # (n+1, B), (n+1, B), (n+1,)
+            routed_np, scores_np, frac_np = _decode_aux_to_host(aux)
         if self._faults is not None:
             logits_np = self._faults.corrupt_logits(self, logits_np)
         # finiteness police over the whole verify window: a poisoned row
         # fails only its own request, and leaves the accept loop before it
         # can drag the batch-global acceptance down with it
-        ok_slots = []
-        for s in active_slots:
-            if np.isfinite(logits_np[:, s.idx]).all():
-                ok_slots.append(s)
-            else:
-                self._finish(
-                    s, FINISH_ERROR,
-                    error=f"non-finite verify logits at step {self.step_count}",
-                )
+        with _span("serve.sample"):
+            ok_slots = []
+            for s in active_slots:
+                if np.isfinite(logits_np[:, s.idx]).all():
+                    ok_slots.append(s)
+                else:
+                    self._finish(
+                        s, FINISH_ERROR,
+                        error=f"non-finite verify logits at step {self.step_count}",
+                    )
         active_slots = ok_slots
         if not active_slots:
             # every active row failed: nothing was accepted, so there is
@@ -1739,7 +1765,7 @@ class ServingEngine:
             # _finish, and pool.resid still holds the pre-round state
             self.step_count += 1
             self._step_epilogue(t0)
-            self.scheduler.check_invariants(self.slots, len(self.finished))
+            self._check_invariants()
             return self.finished[done_before:]
 
         # Per-slot acceptance: emitted token k+1 samples from the verify
@@ -1748,60 +1774,55 @@ class ServingEngine:
         # Sampling is fold_in(key, token_index)-deterministic, so tokens
         # sampled past the global cap are re-sampled identically from the
         # same logits next round.
-        emitted: Dict[int, List[int]] = {}
-        a = n + 1
-        for s in active_slots:
-            toks: List[int] = []
-            c_s = n + 1
-            for k in range(n + 1):
-                e = self._sample(s.req, logits_np[k, s.idx], len(s.generated) + k)
-                toks.append(e)
-                if (
-                    e == s.req.eos_id
-                    or len(s.generated) + k + 1 >= s.req.max_new_tokens
-                ):
-                    c_s = k + 1  # in-window termination caps the batch
-                    break
-                if k < n and e != int(drafts_np[k, s.idx]):
-                    c_s = k + 1  # draft mismatch: L_{k+1}.. are invalid
-                    break
-            emitted[s.idx] = toks
-            a = min(a, c_s)
+        with _span("serve.sample"):
+            emitted: Dict[int, List[int]] = {}
+            a = n + 1
+            for s in active_slots:
+                toks: List[int] = []
+                c_s = n + 1
+                for k in range(n + 1):
+                    e = self._sample(s.req, logits_np[k, s.idx], len(s.generated) + k)
+                    toks.append(e)
+                    if (
+                        e == s.req.eos_id
+                        or len(s.generated) + k + 1 >= s.req.max_new_tokens
+                    ):
+                        c_s = k + 1  # in-window termination caps the batch
+                        break
+                    if k < n and e != int(drafts_np[k, s.idx]):
+                        c_s = k + 1  # draft mismatch: L_{k+1}.. are invalid
+                        break
+                emitted[s.idx] = toks
+                a = min(a, c_s)
 
-        routed = aux.get("mod/decode_routed")  # (n+1, B)
-        scores = aux.get("mod/decode_scores")
-        routed_np = None if routed is None else np.asarray(routed)
-        scores_np = None if scores is None else np.asarray(scores)
-        frac = aux.get("mod/decode_routed_frac")  # (n+1,)
-        if frac is not None:
-            frac_np = np.asarray(frac)
-            self._routed_frac_sum += float(frac_np[:a].sum())
-            self._routed_frac_steps += a
-        self._occupancy_sum += len(active_slots) * a
-        # the round's fixed grid is n+1 verify positions per row, plus the
-        # n-step draft grid when drafting is a separate pass (_spec_grid);
-        # only the accepted tokens of active rows carried real work —
-        # rejected verify positions and any draft grid count as
-        # speculation overhead in padded_token_fraction
-        self._positions_computed += self._spec_grid * B
-        self._positions_wasted += self._spec_grid * B - a * len(active_slots)
+            if frac_np is not None:
+                self._routed_frac_sum += float(frac_np[:a].sum())
+                self._routed_frac_steps += a
+            self._occupancy_sum += len(active_slots) * a
+            # the round's fixed grid is n+1 verify positions per row, plus the
+            # n-step draft grid when drafting is a separate pass (_spec_grid);
+            # only the accepted tokens of active rows carried real work —
+            # rejected verify positions and any draft grid count as
+            # speculation overhead in padded_token_fraction
+            self._positions_computed += self._spec_grid * B
+            self._positions_wasted += self._spec_grid * B - a * len(active_slots)
 
-        for s in active_slots:
-            for k in range(a):
-                if routed_np is not None:
-                    s.routed_sum += float(routed_np[k, s.idx])
-                    s.routed_steps += 1
-                if scores_np is not None:
-                    s.score = float(scores_np[k, s.idx])
-                    s.score_sum += s.score
-                    s.score_steps += 1
-                s.pos += 1
-                self._push_token(s, emitted[s.idx][k])
-                if s.req is None:
-                    # the global cap places any termination at k == a-1
-                    assert k == a - 1, (k, a)
-                    break
-                s.next_token = emitted[s.idx][k]
+            for s in active_slots:
+                for k in range(a):
+                    if routed_np is not None:
+                        s.routed_sum += float(routed_np[k, s.idx])
+                        s.routed_steps += 1
+                    if scores_np is not None:
+                        s.score = float(scores_np[k, s.idx])
+                        s.score_sum += s.score
+                        s.score_steps += 1
+                    s.pos += 1
+                    self._push_token(s, emitted[s.idx][k])
+                    if s.req is None:
+                        # the global cap places any termination at k == a-1
+                        assert k == a - 1, (k, a)
+                        break
+                    s.next_token = emitted[s.idx][k]
 
         # rollback: restore the residual stack (MoD rings + cursors) to
         # the state after exactly `a` verify steps, and release the
@@ -1818,7 +1839,7 @@ class ServingEngine:
         self._spec_emitted += a
         self.step_count += a
         self._step_epilogue(t0)
-        self.scheduler.check_invariants(self.slots, len(self.finished))
+        self._check_invariants()
         return self.finished[done_before:]
 
     def run(self, max_steps: Optional[int] = None) -> List[RequestOutput]:

@@ -86,9 +86,10 @@ def make_train_step(
 
     def step_fn(state: State, batch) -> Tuple[State, Dict[str, jax.Array]]:
         loss, aux, grads = grads_of(state["params"], batch, state["step"])
-        grads, gnorm = clip_by_global_norm(grads, ocfg.clip_norm)
-        lr = cosine_schedule(state["step"], ocfg)
-        params, opt = adamw_update(state["params"], grads, state["opt"], ocfg, lr)
+        with jax.named_scope("optimizer"):
+            grads, gnorm = clip_by_global_norm(grads, ocfg.clip_norm)
+            lr = cosine_schedule(state["step"], ocfg)
+            params, opt = adamw_update(state["params"], grads, state["opt"], ocfg, lr)
         metrics = {k: v for k, v in aux.items()}
         metrics.update({"grad_norm": gnorm, "lr": lr, "loss": loss})
         new_state = {"params": params, "opt": opt, "step": state["step"] + 1}

@@ -1,6 +1,7 @@
 """Small shared utilities: pytree helpers, PRNG splitting, param counting."""
 from __future__ import annotations
 
+import functools
 import json
 import os
 from pathlib import Path
@@ -30,6 +31,23 @@ def enable_compile_cache() -> str:
         path = str(REPO_ROOT / ".jax_cache")
         jax.config.update("jax_compilation_cache_dir", path)
     return path
+
+
+def scoped(name: str):
+    """Decorator: trace the function under ``jax.named_scope(name)``, so each
+    op it stages carries ``name`` in its HLO ``op_name`` and a profiler
+    trace can sum device time by layer. A fresh scope per call keeps nested
+    and concurrent traces apart."""
+
+    def wrap(fn):
+        @functools.wraps(fn)
+        def inner(*args, **kwargs):
+            with jax.named_scope(name):
+                return fn(*args, **kwargs)
+
+        return inner
+
+    return wrap
 
 
 def key_iter(seed_or_key) -> Iterator[jax.Array]:
