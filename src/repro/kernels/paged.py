@@ -21,10 +21,17 @@ Both run in ``interpret=True`` on CPU (validated against ``kernels/ref.py``
 oracles in tests/test_paged.py) and lower to Mosaic on TPU. The canonical
 layout is ``pages (N, p, F)`` / ``rows (B, F)``; the leaf-shaped wrappers
 in ``kernels/ops.py`` fold arbitrary lead/tail dims into F.
+
+``paged_decode_attention`` is the padded decode step's attention read: one
+query per slot against that slot's K/V, read through the page table straight
+out of the layer-stacked pool (its ``(L, N, nkv, p, hd)`` view) and only up
+to the slot's last live page. Its oracle is the XLA formulation in
+``models/paged_kv.py`` (gather by the table, then ``attend``).
 """
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -201,3 +208,183 @@ def paged_scatter_rows_pallas(
         out_shape=jax.ShapeDtypeStruct((N, p, F), pages.dtype),
         interpret=interpret,
     )(pid.astype(jnp.int32), off, rows.astype(pages.dtype), pages)
+
+
+# ---------------------------------------------------------------------------
+# Live-page decode attention over the layer-stacked pool
+# ---------------------------------------------------------------------------
+
+# lanes of the pos block: a page's positions are a column of the (p, N) view
+# of the pos pages, fetched as the aligned 128-page block holding it
+_POS_LANES = 128
+NEG_INF = -1e30  # kernels/flash_attention.NEG_INF, the masked-score value
+
+
+def _live_page(i, b, j, tbl, npg, ppb):
+    """Physical page behind input ``i`` of grid step ``(b, j)``: logical page
+    ``j * ppb + i``, clamped to the slot's last live page, and blocks past
+    that page repeat the last live block, so their inputs keep the previous
+    step's block index and no DMA is issued for them."""
+    last = npg[b] - 1
+    lp = jnp.minimum(jnp.minimum(j, last // ppb) * ppb + i, last)
+    return tbl[b, lp]
+
+
+def _live_decode_kernel(
+    tbl_ref,  # (B, P) scalar-prefetch page table
+    lay_ref,  # (1,) scalar-prefetch layer of the stack (read by the index maps)
+    npg_ref,  # (B,) scalar-prefetch live pages per slot
+    qpos_ref,  # (B,) scalar-prefetch query positions
+    q_ref,  # (g, nkv, 1, hd) this slot's query heads, grouped by kv head
+    *refs,  # ppb k pages (nkv, p, hd), ppb v pages, ppb pos blocks (p, 128),
+    #         out (g, nkv, 1, hd), then the acc / max / denominator scratch
+    scale: float,
+    causal: bool,
+    window: int,
+    ppb: int,
+    n_blocks: int,
+):
+    """One (slot, block of ``ppb`` pages) grid step over every head. Pages
+    past the slot's last live page are skipped; the online softmax is the
+    ragged kernel's, in f32, on the VPU (one query row per head)."""
+    k_refs, v_refs, pos_refs = refs[:ppb], refs[ppb : 2 * ppb], refs[2 * ppb : 3 * ppb]
+    o_ref, acc_ref, m_ref, l_ref = refs[3 * ppb :]
+    b = pl.program_id(0)
+    j = pl.program_id(1)
+    npg = npg_ref[b]
+    qp = qpos_ref[b]
+
+    @pl.when(j == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
+        l_ref[...] = jnp.zeros_like(l_ref)
+
+    for i in range(ppb):
+        lp = j * ppb + i
+
+        @pl.when(lp < npg)
+        def _page(i=i, lp=lp):
+            rows = pos_refs[i][...]  # (p, 128): the aligned block of columns
+            lane = jax.lax.broadcasted_iota(jnp.int32, rows.shape, 1)
+            hit = lane == tbl_ref[b, lp] % _POS_LANES
+            kp = jnp.sum(jnp.where(hit, rows, 0), axis=1, keepdims=True)  # (p, 1)
+            valid = kp >= 0
+            if causal:
+                valid &= kp <= qp
+            if window > 0:
+                valid &= qp - kp < window
+            valid = valid[None]  # (1, p, 1)
+            k = k_refs[i][...].astype(jnp.float32)  # (nkv, p, hd)
+            v = v_refs[i][...].astype(jnp.float32)
+            for r in range(q_ref.shape[0]):
+                q = q_ref[r].astype(jnp.float32)  # (nkv, 1, hd)
+                s = jnp.sum(k * q, axis=-1, keepdims=True) * scale  # (nkv, p, 1)
+                s = jnp.where(valid, s, NEG_INF)
+                m_prev = m_ref[r]  # (nkv, 1, 1)
+                m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+                m_safe = jnp.where(m_new > NEG_INF / 2, m_new, 0.0)
+                e = jnp.where(valid, jnp.exp(s - m_safe), 0.0)
+                corr = jnp.where(m_prev > NEG_INF / 2, jnp.exp(m_prev - m_safe), 0.0)
+                l_ref[r] = l_ref[r] * corr + jnp.sum(e, axis=1, keepdims=True)
+                acc_ref[r] = acc_ref[r] * corr + jnp.sum(e * v, axis=1, keepdims=True)
+                m_ref[r] = m_new
+
+    @pl.when(j == n_blocks - 1)
+    def _finish():
+        o_ref[...] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
+
+
+def paged_decode_attention(
+    q: jax.Array,  # (B, nq, hd) one query per slot
+    k_pages: jax.Array,  # (L, N, nkv, p, hd) layer-stacked pool
+    v_pages: jax.Array,  # (L, N, nkv, p, hd)
+    pos_pages: jax.Array,  # (L, p, N) int32 absolute positions; -1 = empty
+    table: jax.Array,  # (B, P) int32 per-slot page table
+    layer: jax.Array,  # () int32: which of the L stacked layers to read
+    q_pos: jax.Array,  # (B,) int32 query positions
+    *,
+    causal: bool = True,
+    window: int = 0,
+    scale: Optional[float] = None,
+    pages_per_block: int = 8,
+    interpret: bool = False,
+) -> jax.Array:  # (B, nq, hd) f32
+    """Decode attention read through the page table, live pages only.
+
+    Slot ``b`` reads logical pages ``0 .. q_pos[b] // p`` (its live length)
+    of layer ``layer``; each grid step covers ``pages_per_block`` of them,
+    and the layer index is a scalar-prefetch operand, so the stack is never
+    sliced. Rows are masked by their stored positions as ``attend`` masks
+    them (``pos >= 0``, causal, window); a slot with no valid row returns
+    zeros. Positions past a slot's own never hold valid rows in the decode
+    step (pages map lazily and are scrubbed when mapped), which is what lets
+    the read stop at the live length.
+
+    K/V come as ``(L, N, nkv, p, hd)`` and positions as ``(L, p, N)``: the
+    pool's ``(L, N, p, nkv, hd)`` and ``(L, N, p)`` pages with the in-page
+    axis moved next to the last, which is how the TPU lays them out for
+    ``p = 16`` (tiles over ``(p, hd)``, and over ``(p, N)`` for positions),
+    so taking them so costs no copy of the pool."""
+    B, nq, hd = q.shape
+    L, N, nkv, p, _ = k_pages.shape
+    P = table.shape[1]
+    assert nq % nkv == 0
+    g = nq // nkv
+    scale = scale if scale is not None else 1.0 / (hd**0.5)
+    ppb = max(1, min(int(pages_per_block), P))
+    nb = -(-P // ppb)
+    npg = jnp.clip(q_pos.astype(jnp.int32) // p + 1, 1, P)
+
+    def kv_spec(i):
+        return pl.BlockSpec(
+            (None, None, nkv, p, hd),
+            lambda b, j, tbl, lay, n, qp: (lay[0], _live_page(i, b, j, tbl, n, ppb), 0, 0, 0),
+        )
+
+    def pos_spec(i):
+        return pl.BlockSpec(
+            (None, p, _POS_LANES),
+            lambda b, j, tbl, lay, n, qp: (
+                lay[0], 0, _live_page(i, b, j, tbl, n, ppb) // _POS_LANES),
+        )
+
+    head_spec = pl.BlockSpec((None, g, nkv, 1, hd), lambda b, j, *_: (b, 0, 0, 0, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,
+        grid=(B, nb),
+        in_specs=[
+            head_spec,
+            *[kv_spec(i) for i in range(ppb)],
+            *[kv_spec(i) for i in range(ppb)],
+            *[pos_spec(i) for i in range(ppb)],
+        ],
+        out_specs=head_spec,
+        scratch_shapes=[
+            pltpu.VMEM((g, nkv, 1, hd), jnp.float32),
+            pltpu.VMEM((g, nkv, 1, 1), jnp.float32),
+            pltpu.VMEM((g, nkv, 1, 1), jnp.float32),
+        ],
+    )
+    kernel = functools.partial(
+        _live_decode_kernel, scale=float(scale), causal=bool(causal),
+        window=int(window), ppb=ppb, n_blocks=nb,
+    )
+    # query head h = hk * g + r reads kv head hk (GQA)
+    qg = q.reshape(B, nkv, g, 1, hd).transpose(0, 2, 1, 3, 4)
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, g, nkv, 1, hd), jnp.float32),
+        interpret=interpret,
+    )(
+        table.astype(jnp.int32),
+        jnp.reshape(layer, (1,)).astype(jnp.int32),
+        npg,
+        q_pos.astype(jnp.int32),
+        qg,
+        *([k_pages] * ppb),
+        *([v_pages] * ppb),
+        *([pos_pages.astype(jnp.int32)] * ppb),
+    )
+    return out.transpose(0, 2, 1, 3, 4).reshape(B, nq, hd)

@@ -25,6 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.config import ModelConfig
+from repro.models import paged_kv as PKV
 from repro.models.layers import _dense_init, apply_mrope, apply_rope
 from repro.utils import scoped
 
@@ -518,6 +519,9 @@ def decode_attention(
     The cache stores *rotated* K — RoPE's relative property only needs each
     key rotated at its own absolute position, so nothing is re-rotated at
     read time (O(1) rotation per step even at 500k context).
+
+    A cache whose rings are the serving pool's pages (``paged_kv.PagedLeaf``)
+    is written and read in place (``paged_kv.write_rows``, ``attend_paged``).
     """
     q = _project_q(params, x, cfg)
     k_new, v_new = _project_kv(params, x, cfg)
@@ -536,6 +540,9 @@ def decode_attention(
         k_new = constrain_spec(k_new, bd, None, None, "model")
         v_new = constrain_spec(v_new, bd, None, None, "model")
     tp = _t_pos(positions)
+    if PKV.is_paged(cache["k"]):
+        cache = PKV.write_rows(cache, k_new[:, 0], v_new[:, 0], tp[:, 0])
+        return PKV.attend_paged(q, cache, tp, cfg) @ params["wo"], cache
     cache = cache_write(cache, k_new, v_new, tp)
     mask = make_mask(tp, cache["pos"], cfg.attn.causal, cfg.attn.window)
     out = attend(q, cache["k"], cache["v"], mask, cfg) @ params["wo"]

@@ -20,6 +20,7 @@ from repro.core import router as R
 from repro.core import routing as ROUT
 from repro.models import attention as A
 from repro.models import blocks as BLK
+from repro.models.paged_kv import scan_layers
 from repro.distributed.sharding import constrain_batch
 from repro.utils import scan_or_loop
 from repro.models.layers import (
@@ -275,7 +276,7 @@ def decode_step(
             aux.update(a)
         return constrain_batch(h), (new_c, aux)
 
-    x, (new_groups, aux_stack) = scan_or_loop(body, x, (params["groups"], caches["groups"]), unroll=cfg.unroll_layers)
+    x, (new_groups, aux_stack) = scan_layers(body, x, (params["groups"], caches["groups"]), unroll=cfg.unroll_layers)
     # mean over the layer-group axis only (per-sequence telemetry keeps (B,))
     aux = jax.tree.map(lambda a: jnp.mean(a, axis=0), aux_stack)
     logits = lm_head(params, x, cfg)[:, 0]

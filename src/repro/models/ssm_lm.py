@@ -23,6 +23,7 @@ from repro.core import router as R
 from repro.core import routing as ROUT
 from repro.models import attention as A
 from repro.models import blocks as BLK
+from repro.models.paged_kv import scan_layers
 from repro.models import ssm as SSM
 from repro.distributed.sharding import constrain_batch
 from repro.utils import scan_or_loop
@@ -365,7 +366,7 @@ def decode_step_hybrid(
         # mean over the within-segment pair axis only
         return constrain_batch(h), (new_seg, attn_cache, jax.tree.map(lambda a: jnp.mean(a, axis=0), aux))
 
-    x, (new_groups, new_attn, aux_stack) = jax.lax.scan(
+    x, (new_groups, new_attn, aux_stack) = scan_layers(
         outer_body, x, (params["groups"], caches["groups"], caches["attn"])
     )
     aux = jax.tree.map(lambda a: jnp.mean(a, axis=0), aux_stack)

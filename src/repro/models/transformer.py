@@ -24,6 +24,7 @@ from repro.core import router as R
 from repro.core import routing as ROUT
 from repro.models import attention as A
 from repro.models import blocks as BLK
+from repro.models.paged_kv import scan_layers
 from repro.distributed.sharding import constrain_batch
 from repro.utils import scan_or_loop
 from repro.models.layers import (
@@ -497,7 +498,7 @@ def decode_step(
             aux.update(a)
         return constrain_batch(h), (new_c, aux)
 
-    x, (new_caches, aux_stack) = scan_or_loop(body, x, (params["groups"], caches["groups"]), unroll=cfg.unroll_layers)
+    x, (new_caches, aux_stack) = scan_layers(body, x, (params["groups"], caches["groups"]), unroll=cfg.unroll_layers)
     out_caches: Params = {"groups": new_caches}
     # mean only over the layer-group axis: scalar telemetry stays scalar,
     # per-sequence entries (decode scores / routed masks) keep their (B,)

@@ -276,11 +276,39 @@ def paged_materialize_q(
     return jax.tree_util.tree_unflatten(spec.treedef, leaves)
 
 
-def paged_materialize(
+def inplace_decode(spec: PoolSpec) -> bool:
+    """Whether the padded decode step reads and writes this pool's pages in
+    place (``paged_rings`` / ``paged_split``) rather than through
+    :func:`paged_materialize_q` and :func:`paged_writeback_q`: unquantized
+    pools whose paged leaves have at most a layer-stack lead axis. Quantized
+    pages keep the gather, which widens them (and its ``paged_backend``)."""
+    return not spec.quant_ids and all(ax <= 1 for ax in spec.paged_axes)
+
+
+def paged_rings(
     spec: PoolSpec, pages: List[jax.Array], resid: List[jax.Array], table: jax.Array
 ) -> Any:
-    """Unquantized-pool special case of :func:`paged_materialize_q`."""
-    return paged_materialize_q(spec, pages, [], resid, table)
+    """The decode step's cache pytree over the pool's own storage: each
+    paged leaf a ``models.paged_kv.PagedLeaf`` (its page stack and the page
+    table, nothing gathered), each residual leaf as it is."""
+    from repro.models.paged_kv import PagedLeaf, to_leaf
+
+    leaves: List[Any] = [None] * (len(spec.paged_ids) + len(spec.resid_ids))
+    for j, (i, ax) in enumerate(zip(spec.paged_ids, spec.paged_axes)):
+        leaves[i] = PagedLeaf(to_leaf(pages[j], ax), table)
+    for j, i in enumerate(spec.resid_ids):
+        leaves[i] = resid[j]
+    return jax.tree_util.tree_unflatten(spec.treedef, leaves)
+
+
+def paged_split(spec: PoolSpec, caches: Any) -> Tuple[List[jax.Array], List[jax.Array]]:
+    """(pages, resid) of a cache pytree built by :func:`paged_rings`."""
+    from repro.models.paged_kv import from_leaf, is_paged
+
+    leaves = jax.tree_util.tree_leaves(caches, is_leaf=is_paged)
+    return ([from_leaf(leaves[i].pages, ax)
+             for i, ax in zip(spec.paged_ids, spec.paged_axes)],
+            [leaves[i] for i in spec.resid_ids])
 
 
 @scoped("paged.writeback")
@@ -329,20 +357,6 @@ def paged_writeback_q(
             )
     new_resid = [leaves[i] for i in spec.resid_ids]
     return new_pages, new_resid, new_scales
-
-
-def paged_writeback(
-    spec: PoolSpec,
-    new_caches: Any,
-    pages: List[jax.Array],
-    table: jax.Array,
-    pos: jax.Array,
-) -> Tuple[List[jax.Array], List[jax.Array]]:
-    """Unquantized-pool special case of :func:`paged_writeback_q`."""
-    new_pages, new_resid, _ = paged_writeback_q(
-        spec, new_caches, pages, [], table, pos
-    )
-    return new_pages, new_resid
 
 
 def slot_slice(spec: PoolSpec, caches: Any, slot: jax.Array) -> Any:
@@ -420,22 +434,6 @@ def paged_writeback_tokens_q(
             )
     new_resid = [leaves[i] for i in spec.resid_ids]
     return new_pages, new_resid, new_scales
-
-
-def paged_writeback_tokens(
-    spec: PoolSpec,
-    new_caches: Any,
-    pages: List[jax.Array],
-    table: jax.Array,
-    slot: jax.Array,
-    pos: jax.Array,
-    valid: jax.Array,
-) -> Tuple[List[jax.Array], List[jax.Array]]:
-    """Unquantized-pool special case of :func:`paged_writeback_tokens_q`."""
-    new_pages, new_resid, _ = paged_writeback_tokens_q(
-        spec, new_caches, pages, [], table, slot, pos, valid
-    )
-    return new_pages, new_resid
 
 
 def quant_roundtrip(spec: PoolSpec, caches: Any, mask: jax.Array) -> Any:
@@ -522,22 +520,6 @@ def paged_scatter_rows_q(
                 )
             )
     return new_pages, new_scales
-
-
-def paged_scatter_rows(
-    spec: "PoolSpec",
-    rows: List[jax.Array],
-    pages: List[jax.Array],
-    table: jax.Array,
-    slot: jax.Array,
-    pos: jax.Array,
-    valid: jax.Array,
-) -> List[jax.Array]:
-    """Unquantized-pool special case of :func:`paged_scatter_rows_q`."""
-    new_pages, _ = paged_scatter_rows_q(
-        spec, rows, pages, [], table, slot, pos, valid
-    )
-    return new_pages
 
 
 def lru_cached(cache: "OrderedDict", key: Any, make, maxsize: int):
@@ -673,8 +655,12 @@ def _build_pool_ops(cfg: ModelConfig, batch: int, ctx: int, page_size: int,
     # modlint: disable=jit-in-loop -- _build_pool_ops itself is memoized in
     # the module-level _POOL_OPS_CACHE LRU (via _pool_ops), so these four
     # jits are constructed once per (cfg, batch, ctx, page_size, backend,
-    # quant) key, not per engine build
-    return tuple(jax.jit(f) for f in (reset_resid, write, scrub, read))
+    # quant) key, not per engine build. ``write`` and ``scrub`` take the
+    # pages and scales donated, so their page writes land in the pool's own
+    # buffers instead of a copy of the whole pool; the residual leaves are
+    # not donated (callers may hold them across a call).
+    return (jax.jit(reset_resid), jax.jit(write, donate_argnums=(0, 1)),
+            jax.jit(scrub, donate_argnums=(0, 1)), jax.jit(read))
 
 
 def _pool_ops(cfg: ModelConfig, batch: int, ctx: int, page_size: int,
@@ -716,10 +702,12 @@ class PagedCachePool:
     shared prompt prefixes are stored once (hash-chained prefix cache with
     refcounted pages + LRU eviction of unreferenced entries).
 
-    The decode step stays once-compiled and fixed-shape: ``materialize``
-    rebuilds the logical ``(B, ctx)`` cache pytree from the page tables
-    (kernels/paged gather) inside the jitted step, and ``writeback``
-    scatters the step's one new row per slot into its tail page.
+    The padded decode step stays once-compiled and fixed-shape and works on
+    the pages in place (:func:`paged_rings`): each full-attention layer
+    writes its new row into the slot's tail page and reads its live pages
+    through the table. Quantized pools, chunked prefill (``read_slot``) and
+    the ragged and speculative steps still ``materialize`` the logical
+    ``(B, ctx)`` view (kernels/paged gather) and ``writeback`` rows.
     """
 
     def __init__(
@@ -845,12 +833,6 @@ class PagedCachePool:
             quant_groups=self._quant_groups,
             quant_dtypes=self._quant_dtypes,
         )
-
-    def materialize(self, pages, resid, table):
-        return paged_materialize(self.step_spec(), pages, resid, table)
-
-    def writeback(self, new_caches, pages, table, pos):
-        return paged_writeback(self.step_spec(), new_caches, pages, table, pos)
 
     def snapshot_resid(self, work: Any) -> Dict[int, jax.Array]:
         """Residual-leaf snapshot of a batch-1 working cache (the non-paged

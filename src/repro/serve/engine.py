@@ -48,8 +48,12 @@ youngest slot back to the queue front, ``prefill_chunk`` ingests dense/MoE
 prompts in fixed-shape pieces, and ``prefix_cache=True`` reuses
 chunk-aligned shared prompt prefixes (pages + residual-state snapshot)
 bit-identically to a cold run. The decode step remains a single jitted
-fixed-shape function: the page-table gather (materialize) and tail-page
-scatter (writeback) run inside it (DESIGN.md §Serving engine).
+fixed-shape function, and it works on the pages in place: each
+full-attention layer writes its new rows into the slots' tail pages and
+reads their live pages through the page table (models/paged_kv.py); the
+step takes the pages donated. Quantized pools still gather the pool into
+a logical cache (materialize) and scatter rows back (writeback) inside
+the step (DESIGN.md §Serving engine).
 
 SPMD serving
 ------------
@@ -69,7 +73,7 @@ import dataclasses
 import time
 import warnings
 from collections import OrderedDict
-from typing import Any, Callable, Dict, List, Optional
+from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -80,9 +84,12 @@ from repro.core.routing import batch_capacity_k, capacity_ladder
 from repro.serve.cache import (
     CachePool,
     PagedCachePool,
+    inplace_decode,
     paged_collect_rows,
     paged_materialize_q,
+    paged_rings,
     paged_scatter_rows_q,
+    paged_split,
     paged_writeback_q,
     paged_writeback_tokens_q,
     quant_roundtrip,
@@ -130,10 +137,14 @@ _JIT_CACHE_MAX = 32
 _span = jax.profiler.TraceAnnotation
 
 
-def _cached_jit(kind: str, key: Any, make: Callable[[], Callable]) -> Callable:
+def _cached_jit(kind: str, key: Any, make: Callable[[], Callable],
+                donate_argnums: Tuple[int, ...] = ()) -> Callable:
     from repro.serve.cache import lru_cached
 
-    return lru_cached(_JIT_CACHE, (kind, key), lambda: jax.jit(make()), _JIT_CACHE_MAX)
+    return lru_cached(
+        _JIT_CACHE, (kind, key),
+        lambda: jax.jit(make(), donate_argnums=donate_argnums), _JIT_CACHE_MAX,
+    )
 
 
 # One process-wide deprecation notice for legacy ServingEngine(**kwargs)
@@ -409,6 +420,7 @@ class ServingEngine:
             )
         else:
             self.pool = CachePool(cfg, batch_size, ctx, mesh=mesh)
+        self._inplace_decode = self._paged and inplace_decode(self.pool.step_spec())
         self.scheduler = Scheduler(
             batch_size, policy, routed_capacity(cfg, batch_size, shards),
             verify_token_budget=spec_verify_budget,
@@ -429,6 +441,10 @@ class ServingEngine:
         self._positions_wasted = 0
         self._routed_frac_sum = 0.0
         self._routed_frac_steps = 0
+        # padded paged decode: share of the page table the attention reads
+        # (live pages over B * P), summed per decode step
+        self._live_page_share_sum = 0.0
+        self._live_page_steps = 0
         self._occupancy_sum = 0
         # speculative telemetry: accept rate = accepted draft tokens over
         # drafted tokens (the MoD "confident tokens need less depth" signal)
@@ -797,6 +813,21 @@ class ServingEngine:
             spec = self.pool.step_spec()
 
             def _make_paged_step():
+                if inplace_decode(spec):
+                    # the pages ride the layer scan in place: each full
+                    # layer writes its rows into them and reads its live
+                    # pages through the table (models/paged_kv.py)
+                    def step(p, pages, scales, resid, table, t, pos, act):
+                        p = dequantize_params(p)
+                        logits, new_caches, aux = api.model_decode(
+                            p, paged_rings(spec, pages, resid, table), cfg, t,
+                            pos, act, spmd=spmd,
+                        )
+                        new_pages, new_resid = paged_split(spec, new_caches)
+                        return logits, new_pages, new_resid, scales, aux
+
+                    return step
+
                 def step(p, pages, scales, resid, table, t, pos, act):
                     p = dequantize_params(p)
                     caches = paged_materialize_q(spec, pages, scales, resid, table)
@@ -810,11 +841,15 @@ class ServingEngine:
 
                 return step
 
+            # the pages are donated: the step's row writes then land in the
+            # pool's own buffers instead of a copy of the whole pool. The
+            # residual leaves are not (callers may hold them across a step).
             return _cached_jit(
                 "paged_step",
                 (cfg, spmd, self.ctx, self.pool.page_size,
                  self.pool.n_pages, self._paged_backend, self.pool.quant),
                 _make_paged_step,
+                donate_argnums=(1,),
             )
         return _cached_jit(
             "step", (cfg, spmd),
@@ -1463,6 +1498,8 @@ class ServingEngine:
             if lvl:
                 self._degraded_decode_steps += 1
             if self._paged:
+                self._live_page_share_sum += self._live_page_share(pos)
+                self._live_page_steps += 1
                 (logits, self.pool.pages, self.pool.resid, self.pool.scales,
                  aux) = step_fn(
                     self.params, self.pool.pages, self.pool.scales,
@@ -1495,6 +1532,16 @@ class ServingEngine:
         self._step_epilogue(t0)
         self._check_invariants()
         return self.finished[done_before:]
+
+    def _live_page_share(self, pos: np.ndarray) -> float:
+        """Pages of the table a padded decode step's attention reads, over
+        all of them: each slot's live pages (up to its position) where the
+        step reads in place, every page where it gathers the pool."""
+        P = self.pool.pages_per_slot
+        if not self._inplace_decode:
+            return 1.0
+        live = np.minimum(pos // self.pool.page_size + 1, P)
+        return float(live.sum()) / (len(pos) * P)
 
     def _sample_decoded(self, active_slots: List[Slot], logits_np: np.ndarray,
                         routed_np: Optional[np.ndarray],
@@ -1991,6 +2038,12 @@ class ServingEngine:
             "failed": float(self._n_failed),
         }
         if self._paged:
+            # mean share of the page table the padded decode step read
+            out["decode_live_page_share"] = (
+                self._live_page_share_sum / self._live_page_steps
+                if self._live_page_steps
+                else 0.0
+            )
             out["preemptions"] = float(self.preemptions)
             out["admission_aborts"] = float(self.admission_aborts)
             out.update(self.pool.page_stats())

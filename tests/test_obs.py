@@ -51,7 +51,10 @@ def test_paged_decode_step_and_prefill_chunk_carry_the_layer_scopes():
         eng.params, pool.pages, pool.scales, pool.resid, pool.device_table(),
         jnp.zeros((B, 1), jnp.int32), jnp.zeros((B,), jnp.int32), jnp.ones((B,), bool),
     ).compile().as_text()
-    assert set(MODEL_SCOPES) | {"paged.materialize", "paged.writeback"} <= scopes_in(step)
+    # the step writes its rows into the pool in place (paged.writeback); it
+    # gathers no logical cache, so paged.materialize is gone from it
+    assert set(MODEL_SCOPES) | {"paged.writeback"} <= scopes_in(step)
+    assert "paged.materialize" not in scopes_in(step)
     chunk = eng._chunk_fn.lower(
         eng.params, pool.read_slot(0), jnp.zeros((1, 4), jnp.int32), jnp.int32(0),
         jnp.int32(4),

@@ -11,7 +11,7 @@ import pytest
 from repro.config import MoDConfig, SSMConfig
 from repro.kernels import ops, ref
 from repro.models import api
-from repro.serve import Request, ServingEngine
+from repro.serve import EngineConfig, Request, ServingEngine
 from repro.serve.cache import NULL_PAGE, SCRATCH_PAGE, PagedCachePool
 from repro.serve.scheduler import PREFILL
 from tests.helpers import tiny_cfg
@@ -157,6 +157,187 @@ def test_paged_pallas_backend_matches_xla():
             eng.submit(r)
         outs[backend] = {o.uid: o.full_sequence.tolist() for o in eng.run()}
     assert outs["xla"] == outs["pallas"]
+
+
+# ---------------------------------------------------------------------------
+# In-place padded decode: live-page attention, row writes, donation
+# ---------------------------------------------------------------------------
+
+
+def _live_pool(rng, L, N, p, nkv, hd, P, q_pos, free):
+    """A layer-stacked pool as the decode step sees it: each live slot maps
+    its pages up to its position (plus one scrubbed look-ahead page), rows
+    hold their own positions up to the slot's and -1 after, the unmapped
+    tail is NULL; free slots map SCRATCH, whose rows hold garbage."""
+    k = rng.normal(size=(L, N, p, nkv, hd)).astype(np.float32)
+    v = rng.normal(size=(L, N, p, nkv, hd)).astype(np.float32)
+    pos = np.full((L, N, p), -1, np.int32)
+    k[:, NULL_PAGE] = v[:, NULL_PAGE] = 0.0
+    pos[:, SCRATCH_PAGE] = rng.integers(1, 3 * p, size=p)
+    pos[:, SCRATCH_PAGE, 0] = 0
+    table = np.full((len(q_pos), P), NULL_PAGE, np.int32)
+    nxt = 2
+    for b, qp in enumerate(q_pos):
+        if b in free:
+            table[b] = SCRATCH_PAGE
+            continue
+        n = qp // p + 1
+        for i in range(min(P, n + 1)):
+            table[b, i] = nxt
+            t = i * p + np.arange(p)
+            pos[:, nxt] = np.where(t <= qp, t, -1)
+            nxt += 1
+    assert nxt <= N
+    return k, v, pos, table
+
+
+@pytest.mark.parametrize(
+    "nq,nkv,causal,window,ppb",
+    [(4, 2, True, 0, 2), (2, 2, True, 0, 4), (4, 2, True, 5, 2), (4, 4, False, 0, 3)],
+    ids=["gqa", "mha-blocks-of-4", "window", "bidirectional-blocks-of-3"],
+)
+def test_live_page_decode_attention_matches_its_xla_oracle(nq, nkv, causal, window, ppb):
+    """The live-page kernel (interpret mode) against the gather + attend
+    formulation the step runs off TPU, on lengths at and across page and
+    block boundaries, NULL-mapped tails, a full slot and a free slot on
+    SCRATCH, reading layer 1 of a two-layer stack."""
+    from repro.config import AttentionConfig
+    from repro.kernels.paged import paged_decode_attention
+    from repro.models import paged_kv as PKV
+
+    rng = np.random.default_rng(7)
+    L, p, hd, P = 2, 4, 8, 6
+    q_pos = [0, p - 1, p, ppb * p - 1, ppb * p + 1, P * p - 1, 0]
+    free = {len(q_pos) - 1}
+    N = 2 + sum(min(P, qp // p + 2) for b, qp in enumerate(q_pos) if b not in free)
+    k, v, pos, table = _live_pool(rng, L, N, p, nkv, hd, P, q_pos, free)
+    q = jnp.asarray(rng.normal(size=(len(q_pos), nq, hd)), jnp.float32)
+    qp = jnp.asarray(q_pos, jnp.int32)
+    layer = jnp.int32(1)
+    stacks = [PKV.to_leaf(jnp.asarray(a), 1) for a in (k, v, pos)]
+    cfg = tiny_cfg(attn=AttentionConfig(
+        n_heads=nq, n_kv_heads=nkv, head_dim=hd, causal=causal, window=window))
+    cache = {n: PKV.PagedLeaf(a, jnp.asarray(table), layer)
+             for n, a in zip(("k", "v", "pos"), stacks)}
+    want = PKV.attend_paged(q[:, None], cache, qp[:, None], cfg)
+    got = paged_decode_attention(
+        q, *stacks, jnp.asarray(table), layer, qp, causal=causal, window=window,
+        pages_per_block=ppb, interpret=True,
+    )
+    np.testing.assert_allclose(
+        np.asarray(got).reshape(want.shape), np.asarray(want), rtol=1e-5, atol=1e-5
+    )
+
+
+def _decode_engine(B=3, **kw):
+    cfg = tiny_cfg()
+    params = api.init_model(jax.random.PRNGKey(0), cfg)
+    eng = ServingEngine(params, cfg, engine=EngineConfig(
+        batch_size=B, ctx=16, page_size=4, prefill_chunk=4, **kw))
+    rng = np.random.default_rng(11)
+    for n, m in ((5, 6), (3, 9), (7, 4), (2, 7)):
+        eng.submit(Request(tokens=rng.integers(0, cfg.vocab, size=n).astype(np.int32),
+                           max_new_tokens=m))
+    return cfg, eng
+
+
+def _spy_steps(eng, check):
+    """Wrap the engine's decode call: ``check(args, out)`` sees each step's
+    inputs (taken before the call, which donates the pages) and outputs."""
+    step_fn = eng._step_fn
+    calls = []
+
+    def spy(*args):
+        # copies: a numpy view of a CPU buffer would pin it against donation
+        before = tuple([np.array(a, copy=True) for a in args[i]] for i in (1, 3))
+        out = step_fn(*args)
+        check(args, before, out)
+        calls.append(1)
+        return out
+
+    eng._step_fn = spy
+    return calls
+
+
+def test_inplace_decode_leaves_the_pages_of_materialize_and_writeback():
+    """Step after step, the in-place row writes leave every page a request
+    can read (all but SCRATCH) bit-equal to the materialise -> decode ->
+    write-back sequence on the same inputs, with the same residual state and
+    the same logits in every live row."""
+    from repro.serve.cache import paged_materialize_q, paged_writeback_q
+
+    cfg, eng = _decode_engine()
+    spec = eng.pool.step_spec()
+
+    @jax.jit
+    def old(p, pages, resid, table, t, pos, act):
+        caches = paged_materialize_q(spec, pages, [], resid, table)
+        logits, new, _ = api.model_decode(p, caches, cfg, t, pos, act)
+        return (logits,) + paged_writeback_q(spec, new, pages, [], table, pos)[:2]
+
+    def check(args, before, out):
+        p, _, _, _, table, t, pos, act = args
+        pages0, resid0 = ([jnp.asarray(a) for a in x] for x in before)
+        logits, pages, resid = old(p, pages0, resid0, table, t, pos, act)
+        live = np.asarray(act)
+        np.testing.assert_array_equal(np.asarray(out[0])[live], np.asarray(logits)[live])
+        for got, want, ax in zip(out[1], pages, spec.paged_axes):
+            np.testing.assert_array_equal(
+                np.delete(np.asarray(got), SCRATCH_PAGE, axis=ax),
+                np.delete(np.asarray(want), SCRATCH_PAGE, axis=ax))
+        for got, want in zip(out[2], resid):
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+    calls = _spy_steps(eng, check)
+    eng.run()
+    assert len(calls) >= 8
+
+
+def test_paged_step_donates_its_pages_and_keeps_the_residual_pool():
+    """The decode step takes the pages (its writes land in the pool's own
+    buffers) and leaves the residual leaves readable: callers such as a
+    benchmark's recorder hold the MoD cursors from before a step."""
+    _, eng = _decode_engine()
+    seen = []
+
+    def check(args, before, out):
+        seen.append(1)
+        assert all(a.is_deleted() for a in args[1])
+        for a, b in zip(args[3], before[1]):
+            assert not a.is_deleted()
+            np.testing.assert_array_equal(np.asarray(a), b)
+
+    _spy_steps(eng, check)
+    for _ in range(6):
+        eng.step()
+    assert seen
+
+
+def test_decode_live_page_share_counts_the_pages_the_step_reads():
+    """stats()["decode_live_page_share"]: the mean over decode steps of the
+    live pages (up to each slot's position, free slots one page) over B * P;
+    every page where a quantized pool's step gathers the pool."""
+    from repro.serve.quant import QuantConfig
+
+    _, eng = _decode_engine()
+    P, p = eng.pool.pages_per_slot, eng.pool.page_size
+    shares = []
+
+    def check(args, before, out):
+        pos = np.asarray(args[6])
+        shares.append(np.minimum(pos // p + 1, P).sum() / (pos.size * P))
+
+    _spy_steps(eng, check)
+    assert eng.stats()["decode_live_page_share"] == 0.0
+    eng.run()
+    share = eng.stats()["decode_live_page_share"]
+    assert 0.0 < share < 1.0
+    assert share == pytest.approx(float(np.mean(shares)))
+
+    _, qeng = _decode_engine(quant=QuantConfig(kv="int8"))
+    for _ in range(4):
+        qeng.step()
+    assert qeng.stats()["decode_live_page_share"] == 1.0
 
 
 # ---------------------------------------------------------------------------

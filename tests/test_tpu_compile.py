@@ -1,7 +1,8 @@
 """Compile-only checks of the served-path Pallas kernels for a TPU v5e.
 
-Each test compiles one kernel for a *described* (not attached) v5e chip at
-the widths of ``mod-paper-1b`` (d_model 1792, 14 heads of 128, bf16):
+Each test compiles one kernel (one, the engine's whole decode step) for a
+*described* (not attached) v5e chip at the widths of ``mod-paper-1b``
+(d_model 1792, 14 heads of 128, bf16):
 Mosaic refuses here, in a second or two, what would otherwise fail on the
 chip — block shapes off the (8, 128) tiling, stores at sublane offsets it
 cannot prove aligned, more VMEM than a kernel may use. Nothing runs, so
@@ -130,6 +131,44 @@ def test_ragged_attention(one_chip, quant):
         return rg.ragged_paged_flash_attention(*a[:8], seg_cap=C, **scales)
 
     _compile(one_chip, attn, *shapes)
+
+
+def test_paged_decode_attention(one_chip):
+    """The padded decode step's live-page read at the decode cell's size:
+    16 slots, ctx 2048 in 16-token pages, 12 stacked layers, pool in the
+    layout the step hands it (in-page axis next to the last)."""
+    slots, P = 16, 2048 // PAGE
+    N = slots * P + 2
+    _compile(one_chip, lambda *a: pg.paged_decode_attention(*a),
+             ((slots, H, HD), BF16), ((GROUPS, N, H, PAGE, HD), BF16),
+             ((GROUPS, N, H, PAGE, HD), BF16), ((GROUPS, PAGE, N), I32),
+             ((slots, P), I32), ((), I32), ((slots,), I32))
+
+
+def test_paged_decode_step_reads_the_pool_in_place(one_chip):
+    """The engine's padded paged decode step compiled for the chip: the
+    live-page kernel reads the pool through the table, and no full ring
+    ``(B, ctx, nkv, hd)`` nor stacked ``(G, B, ctx, ...)`` K/V buffer is
+    built."""
+    from repro.models import api
+    from repro.serve import EngineConfig, ServingEngine
+    from tests.helpers import tiny_cfg
+
+    cfg = tiny_cfg()
+    slots, ctx = 4, 32
+    eng = ServingEngine(api.init_model(jax.random.PRNGKey(0), cfg), cfg,
+                        engine=EngineConfig(batch_size=slots, ctx=ctx, page_size=4,
+                                            prefill_chunk=4))
+    pool = eng.pool
+    args = (eng.params, pool.pages, pool.scales, pool.resid, pool.device_table(),
+            jnp.zeros((slots, 1), I32), jnp.zeros((slots,), I32), jnp.ones((slots,), bool))
+    shapes = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), args)
+    text = eng._step_fn.lower(*shapes).compile().as_text()
+    assert "tpu_custom_call" in text
+    nkv, hd, G = cfg.attn.n_kv_heads, cfg.head_dim, cfg.n_layers // 2
+    assert f"[{slots},{ctx},{nkv},{hd}]" not in text
+    assert f"[{G},{slots},{ctx}," not in text
 
 
 def test_fused_dispatch_refuses_to_compile(one_chip):
