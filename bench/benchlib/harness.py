@@ -5,6 +5,12 @@ change adds a cell, a traffic mix, a configuration or a metric by adding files
 and entries, never by editing this one:
 
 - the configuration: the file its ``configs`` entry names;
+- the model family: ``bench/families/<family>.py``, named by the
+  configuration file's ``family`` (``DEFAULT_FAMILY``, the MoD transformer,
+  where it names none). It supplies all that depends on the architecture:
+  the spec read from the file (``spec``), the program's config
+  (``program_config``), the weight leaves (``spec.leaves()``), the float32
+  reference, the FLOP and byte counts, and the check's numbers;
 - the traffic mix: ``bench/traffic/<traffic>.json`` (its ``kind`` picks the
   runner: ``serve`` or ``train``);
 - each metric: ``bench/metrics/<name>.py``, a reader with ``read(run)``;
@@ -18,10 +24,12 @@ import json
 import os
 import sys
 from pathlib import Path
+from types import ModuleType
 from typing import Any, Callable, Dict, List, Optional
 
 BENCH = Path(__file__).resolve().parents[1]
 ROOT = BENCH.parent
+DEFAULT_FAMILY = "mod_transformer"
 
 
 class NoAccelerator(RuntimeError):
@@ -40,6 +48,10 @@ class Cell:
     @property
     def name(self) -> str:
         return self.workload["name"]
+
+    @property
+    def family(self) -> str:
+        return self.config.get("family", DEFAULT_FAMILY)
 
 
 def _reports(metric: Dict[str, Any], cell: str) -> bool:
@@ -70,6 +82,22 @@ def reader(metric: str, root: Path = ROOT) -> Callable[[Any], Optional[float]]:
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod.read
+
+
+def family(name: str, root: Path = ROOT) -> ModuleType:
+    """The model family module ``bench/families/<name>.py``, loaded once per
+    file."""
+    path = (root / "bench" / "families" / f"{name}.py").resolve()
+    if path not in _FAMILIES:
+        spec = importlib.util.spec_from_file_location(f"bench_family_{name}", path)
+        mod = importlib.util.module_from_spec(spec)
+        sys.modules[spec.name] = mod  # dataclasses look their module up
+        spec.loader.exec_module(mod)
+        _FAMILIES[path] = mod
+    return _FAMILIES[path]
+
+
+_FAMILIES: Dict[Path, ModuleType] = {}
 
 
 def check_device(chips: int):

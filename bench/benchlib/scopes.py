@@ -22,6 +22,7 @@ from __future__ import annotations
 
 import bisect
 import collections
+import functools
 import glob
 import os
 import re
@@ -207,6 +208,7 @@ def host_window(host_plane) -> Optional[Tuple[float, float]]:
 _WRAP = re.compile(r"^[\w.\-]*\((.*)\)$")
 
 
+@functools.lru_cache(maxsize=None)
 def scope_path(op_name: str) -> Tuple[str, ...]:
     """The names of an ``op_name``'s path, transformation wrappers taken off:
     ``jit(step_fn)/transpose(jvp(attention))/dot_general`` -> (``step_fn``,
@@ -221,6 +223,7 @@ def scope_path(op_name: str) -> Tuple[str, ...]:
     return tuple(out)
 
 
+@functools.lru_cache(maxsize=None)
 def instruction(event_name: str) -> str:
     """The HLO instruction an op event names: ``%fusion.12 = bf16[..] ...`` ->
     ``fusion.12``."""

@@ -5,34 +5,30 @@ configuration's settings, and queues the warm-up requests and every request
 of the backlog. Warm-up steps the engine until every warm-up request has
 finished, which compiles every program the window uses and staggers the
 slots. The window steps the engine for the stated seconds. Afterwards the
-check holds what the window served to the float32 reference
-(``reference.serve_gaps``): a sample of the finished requests' tokens, and
-the rows that each routed block of the window's last decode steps chose.
+model family's check (``serve_check``) holds what the window served to its
+float32 reference: a sample of the finished requests, drawn from the seed,
+with the longest among them, and whatever else the family compares.
 
 ``Recorder`` wraps the engine's step and, passing their arguments through
 untouched, its prefill-chunk and decode-step calls. Around each call it writes
-a host span, and it keeps references to the routed rings' positions and
-cursors (small arrays: nothing is copied and nothing waits for the device),
-read from the pool through its own public description. From these the check
-learns which tokens each routed block ran on, and the FLOP and byte counts
-learn the routed rows. An engine step whose slots decoded through a call the
-recorder did not see is counted, and fails the check.
+a host span and notes the live rows of each decode call; a family's recorder
+keeps more of each call through the ``keep_*`` hooks (small arrays: nothing is
+copied and nothing waits for the device). An engine step whose slots decoded
+through a call the recorder did not see is counted (``unrecorded``); every
+family's check fails on it.
 """
 from __future__ import annotations
 
 import contextlib
 import dataclasses
-import functools
 import gc
 import time
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
-from . import flops as FL
 from . import traffic as TR
 from .harness import log
-from .spec import ModelSpec
 
 
 def held(nums: Dict[str, float], limits: Dict[str, float]) -> Dict[str, Dict[str, float]]:
@@ -54,30 +50,23 @@ def span(on: bool, name: str):
 @dataclasses.dataclass
 class Step:
     """One decode call: when it was dispatched, the live rows ``(slot, uid,
-    position)``, the routed rings' cursors before and after it (G, B), and the
-    rows routed as the step itself reports them (its ``mod/decode_routed``
-    aux: per row, the share of routed blocks that took it)."""
+    position)``, and what the family's recorder kept before the call, of its
+    outputs, and after the engine step."""
 
     t: float
     live: list
-    cin: Any
-    cout: Any = None
-    reported: Any = None
+    before: Any = None
+    out: Any = None
+    after: Any = None
 
 
 class Recorder:
-    """Wraps one engine's calls (see the module docstring)."""
+    """Wraps one engine's calls (see the module docstring). A family that
+    needs more of each call subclasses it and overrides the ``keep_*`` hooks
+    and ``fetch``."""
 
-    def __init__(self, engine: Any, cfg: Any, chunk: int, spans: bool):
-        import jax
-
-        from repro.models import api
-
+    def __init__(self, engine: Any, chunk: int, spans: bool):
         self.spans, self.chunk = spans, chunk
-        paths = [jax.tree_util.keystr(p) for p, _ in jax.tree_util.tree_flatten_with_path(
-            api.make_caches(cfg, engine.batch_size, engine.ctx, specs=True))[0]]
-        self.cursor_j = engine.pool.step_spec().resid_ids.index(
-            paths.index("['groups']['mod']['cursor']"))
         self.chunks: Dict[int, List[Tuple[float, Any]]] = {}
         self.steps: List[Step] = []
         self.unrecorded = 0  # engine steps whose slots decoded unseen
@@ -85,7 +74,6 @@ class Recorder:
         self._step: Optional[Step] = None
         prefill, chunk_fn, step_fn = engine._chunked_prefill, engine._chunk_fn, engine._step_fn
         engine_step = engine.step
-        cursor = lambda: engine.pool.resid[self.cursor_j]  # noqa: E731
 
         def chunked_prefill(slot, req, *a, **k):
             self._uid = req.uid
@@ -96,16 +84,15 @@ class Recorder:
             t = time.perf_counter()
             with span(self.spans, "prefill_chunk.dispatch"):
                 out = chunk_fn(*a, **k)
-            self.chunks[self._uid].append((t, out[-1]["groups"]["mod"]["pos"]))
+            self.chunks[self._uid].append((t, self.keep_chunk(out)))
             return out
 
         def step_call(*a, **k):
             live = [(s.idx, s.req.uid, s.pos) for s in engine.slots if s.active]
-            self._step = Step(time.perf_counter(), live, cursor())
+            self._step = Step(time.perf_counter(), live, self.keep_before())
             with span(self.spans, "decode_step.dispatch"):
                 out = step_fn(*a, **k)
-            self._step.reported = next((o["mod/decode_routed"] for o in out
-                                        if isinstance(o, dict) and "mod/decode_routed" in o), None)
+            self._step.out = self.keep_out(out)
             return out
 
         def step():
@@ -113,7 +100,7 @@ class Recorder:
             self._step = None
             done = engine_step()
             if self._step is not None:
-                self._step.cout = cursor()
+                self._step.after = self.keep_after()
                 self.steps.append(self._step)
             elif decoding:
                 self.unrecorded += 1
@@ -124,25 +111,31 @@ class Recorder:
         engine._step_fn = step_call
         engine.step = step
 
-    def fetch(self) -> None:
-        """Bring the kept arrays to the host (after the window)."""
-        import jax
+    def keep_chunk(self, out: Any) -> Any:
+        """What to keep of a prefill chunk call's outputs."""
+        return None
 
-        cin, cout, rep = jax.device_get(
-            [[s.cin for s in self.steps], [s.cout for s in self.steps],
-             [s.reported for s in self.steps]])
-        self.routed = [np.asarray(o) - np.asarray(i) for i, o in zip(cin, cout)]  # (G, B)
-        self.cursor = [np.asarray(o) for o in cout]
-        self.reported = [None if r is None else np.asarray(r) for r in rep]
-        self.chunk_pos = {u: [np.asarray(p)[:, 0] for p in jax.device_get([c[1] for c in v])]
-                          for u, v in self.chunks.items()}  # (G, ring) per chunk
+    def keep_before(self) -> Any:
+        """What to keep before a decode call."""
+        return None
+
+    def keep_out(self, out: Any) -> Any:
+        """What to keep of a decode call's outputs."""
+        return None
+
+    def keep_after(self) -> Any:
+        """What to keep after the engine step that made a decode call."""
+        return None
+
+    def fetch(self) -> None:
+        """Bring what was kept to the host (after the window)."""
 
 
 @dataclasses.dataclass
 class ServeRun:
     """What the metric readers read of a serving run."""
 
-    spec: ModelSpec
+    spec: Any
     window_s: float
     tokens: int  # generated in the window
     steps: int  # engine steps in the window
@@ -154,140 +147,15 @@ class ServeRun:
     t_open: float = 0.0  # window open, on time.perf_counter
 
 
-def _decode_work(spec: ModelSpec, rec: Recorder, i: int, ring: int) -> Tuple[float, float]:
-    live = rec.steps[i].live
-    pos = [p for _, _, p in live]
-    routed = [[min(ring, int(rec.cursor[i][g, b])) for b, _, _ in live if rec.routed[i][g, b]]
-              for g in range(spec.n_groups)]
-    return FL.decode_step_flops(spec, pos, routed), FL.decode_step_bytes(spec, pos, routed)
-
-
-def _chunk_work(spec: ModelSpec, pos_leaf: np.ndarray, start: int, nv: int) -> float:
-    ring = []
-    for g in range(spec.n_groups):
-        p = pos_leaf[g]
-        mine = np.sort(p[(p >= start) & (p < start + nv)])
-        valid = np.sort(p[p >= 0])
-        ring.append(np.searchsorted(valid, mine, side="right"))
-    return FL.chunk_flops(spec, start, nv, ring)
-
-
-def routing_of(rec: Recorder, uid: int, L: int, n: int, G: int) -> Optional[np.ndarray]:
-    """(G, L + n - 1) bool: which positions each routed block ran on, or None
-    if a chunk or a decode step of the request was not recorded."""
-    C = rec.chunk
-    chunks = rec.chunk_pos.get(uid, [])
-    if len(chunks) != -(-L // C):
-        return None
-    R = np.zeros((G, L + n - 1), bool)
-    for k, pos in enumerate(chunks):
-        lo, hi = k * C, min((k + 1) * C, L)
-        for g in range(G):
-            p = pos[g]
-            R[g, p[(p >= lo) & (p < hi)]] = True
-    seen = np.zeros(L + n - 1, bool)
-    seen[:L] = True
-    for i, st in enumerate(rec.steps):
-        for b, u, p in st.live:
-            if u == uid and L <= p < L + n - 1:
-                R[:, p] = rec.routed[i][:, b] > 0
-                seen[p] = True
-    return R if seen.all() else None
-
-
-def rows_off(rec: Recorder, kb: int) -> int:
-    """Decode steps and routed blocks whose routed rows are not as the
-    configuration states: a (step, routed block) pair whose routed live rows
-    number other than ``min(kb, live rows)`` or that routed a free row; a step
-    whose routed rows differ from what it reports itself; a step the recorder
-    did not see."""
-    off = rec.unrecorded
-    for i, st in enumerate(rec.steps):
-        rows = [b for b, _, _ in st.live]
-        free = np.ones(rec.routed[i].shape[1], bool)
-        free[rows] = False
-        off += int(np.sum(rec.routed[i][:, rows].sum(axis=1) != min(kb, len(rows))))
-        off += int(np.sum(rec.routed[i][:, free].sum(axis=1) != 0))
-        if rec.reported[i] is not None:
-            share = (rec.routed[i] > 0).mean(axis=0)
-            off += int(not np.allclose(share[rows], rec.reported[i][rows], atol=1e-6))
-    return off
-
-
-def decode_margins(rank, scores: Dict[int, np.ndarray]) -> np.ndarray:
-    """For each decode step and routed block of ``rank`` (``(live rows, routed
-    (G, B))``), how far, in the live rows' score standard deviations, the best
-    reference score of a live row the block left out lies above the worst of
-    one it routed (0 where the program's top rows are the reference's)."""
-    out = []
-    for live, routed in rank:
-        for g in range(routed.shape[0]):
-            sc = np.array([scores[u][g, p] for _, u, p in live])
-            took = np.array([routed[g, b] > 0 for b, _, _ in live])
-            if took.all() or not took.any():
-                continue
-            viol = max(0.0, float(sc[~took].max() - sc[took].min()))
-            out.append(viol / max(float(sc.std()), 1e-30))
-    return np.asarray(out, np.float64)
-
-
-@functools.lru_cache(maxsize=None)
-def _gaps_fn(spec: ModelSpec, n_chunks: int, ring: int):
-    import jax
-
-    from . import reference as REF
-
-    return jax.jit(lambda P, *a: REF.serve_gaps(P, spec, *a, n_chunks=n_chunks, ring=ring))
-
-
-def _reference_check(spec: ModelSpec, seed: int, items, rank, ctx: int, C: int, ring: int):
-    """Runs the reference once over each of ``items`` (``(uid, prompt, served
-    tokens, routing, sampled)``). Over the sampled requests' served tokens: the
-    mean and the widest gap of a served token's logit below the reference's
-    best, and the share of served tokens that are not the reference's best;
-    over their prefill chunks and routed blocks: the mean and the widest route
-    margin; over the steps of ``rank``: the mean and the widest decode margin
-    (``decode_margins``)."""
-    from . import weights as W
-
-    P = W.params_fn(spec, True)(W.seed_key(seed))
-    fn = _gaps_fn(spec, ctx // C, ring)
-    gaps, margins, scores = [], [], {}
-    flips = 0
-    p = np.arange(ctx)
-    for uid, prompt, served, R, sampled in items:
-        L, n = prompt.size, served.size
-        T = L + n - 1
-        fed = np.zeros(ctx, np.int32)
-        fed[:L], fed[L:T] = prompt, served[:-1]
-        routed = np.zeros((spec.n_groups, ctx), bool)
-        routed[:, :T] = R
-        event_end = np.where(p < L, np.minimum((p // C + 1) * C, L) - 1, p).astype(np.int32)
-        served_next = np.full(ctx, -1, np.int32)
-        served_next[L - 1:T] = served
-        chunk_id = np.where(p < L, p // C, -1).astype(np.int32)
-        gp, top, mg, sc = fn(P, fed, routed, event_end, served_next, chunk_id)
-        scores[uid] = np.asarray(sc)
-        if sampled:
-            gaps.append(np.asarray(gp)[L - 1:T])
-            flips += int(np.sum(np.asarray(top)[L - 1:T] != served))
-            margins.append(np.asarray(mg)[:, : -(-L // C)].ravel())
-    g, m = np.concatenate(gaps), np.concatenate(margins)
-    d = decode_margins(rank, scores)
-    return {"logit_gap_mean": float(g.mean()), "logit_gap_max": float(g.max()),
-            "token_flip_share": flips / g.size, "route_margin_mean": float(m.mean()),
-            "route_margin_max": float(m.max()),
-            "decode_margin_mean": float(d.mean()) if d.size else float("nan"),
-            "decode_margin_max": float(d.max()) if d.size else float("nan"),
-            "decode_rankings": float(d.size)}
-
-
-def run(cell, cfg, spec: ModelSpec, seed: int, seconds: float, trace: bool, devices,
+def run(cell, family, cfg, spec: Any, seed: int, seconds: float, trace: bool, devices,
         clock, trace_dir: str, control: Optional[str] = None,
         fault: Optional[Callable[[Any], None]] = None):
-    """One serving run: (result without ``checks``, checks, ServeRun, peak
-    device memory). ``control`` serves with the program's own int8 path
-    (weights and K/V pages); ``fault(engine)`` breaks the timed path."""
+    """One serving run of ``family``'s model: (result without ``checks``,
+    checks, ServeRun, peak device memory). ``fault(engine)`` breaks the timed
+    path. ``control="int8"`` serves with the program's own int8 path (weights
+    and K/V pages); ``control="fp8"`` puts the reference computed through
+    float8 in the program's place: at each position of the served requests,
+    the check reads the token that it puts first, not the served one."""
     import jax
 
     from repro.serve import EngineConfig, Request, ServingEngine
@@ -300,14 +168,14 @@ def run(cell, cfg, spec: ModelSpec, seed: int, seconds: float, trace: bool, devi
     ecfg = EngineConfig(
         batch_size=int(eng_conf["slots"]), ctx=int(eng_conf["ctx"]),
         page_size=int(eng_conf["page_size"]), prefill_chunk=int(eng_conf["prefill_chunk"]),
-        policy=eng_conf["policy"],
-        quant=QuantConfig(kv=control, weights=control) if control else QuantConfig(),
+        policy=eng_conf["policy"], n_pages=eng_conf.get("n_pages"),
+        quant=QuantConfig(kv="int8", weights="int8") if control == "int8" else QuantConfig(),
     )
     engine = ServingEngine(params, cfg, engine=ecfg)
-    rec = Recorder(engine, cfg, ecfg.prefill_chunk, trace)
+    rec = family.recorder(engine, cfg, spec, ecfg.prefill_chunk, trace)
     if fault is not None:
         fault(engine)
-    ring, C = spec.capacity(ecfg.ctx), ecfg.prefill_chunk
+    C = ecfg.prefill_chunk
 
     reqs = TR.serve_requests(mix, seed, spec.vocab)
     emitted = [0]
@@ -350,23 +218,26 @@ def run(cell, cfg, spec: ModelSpec, seed: int, seconds: float, trace: bool, devi
     log(f"memory_stats after the window: {devices[0].memory_stats()}")
     log(f"window: {window_s:.3f}s, {engine.step_count - steps0} steps, {tokens} tokens, "
         f"{len(in_window)} requests finished; compiles in window {clock.compiles - compiles0}")
+    if ecfg.page_size is not None:
+        log(f"pool: at most {engine.pool.peak_pages_in_use} of {engine.pool.n_pages} pages in "
+            f"use; {engine.preemptions} preemptions")
 
     rec.fetch()
     in_win = [i for i, st in enumerate(rec.steps) if t_open <= st.t <= t_close]
-    decode_work = [_decode_work(spec, rec, i, ring) for i in in_win]
+    decode_work = [family.decode_work(spec, rec, i) for i in in_win]
     chunk_flops = []
     for uid, chunks in rec.chunks.items():
         L = by_uid[uid].prompt.size
         for k, (t, _) in enumerate(chunks):
             if t_open <= t <= t_close:
-                chunk_flops.append(_chunk_work(spec, rec.chunk_pos[uid][k], k * C,
-                                               min(C, L - k * C)))
+                chunk_flops.append(family.chunk_work(spec, rec, uid, k, k * C,
+                                                     min(C, L - k * C)))
     record = ServeRun(spec, window_s, tokens, engine.step_count - steps0, decode_work,
                       chunk_flops, t_open=t_open)
 
     # the check: a sample of the window's finished requests drawn from the
-    # seed, with the longest among them, and every request live in the
-    # window's last decode steps, against the reference
+    # seed, with the longest among them, and what else the family compares
+    # of the window's last decode steps, against the family's reference
     done = [o for o in in_window if o.ok]
     sample = []
     if done:
@@ -375,30 +246,16 @@ def run(cell, cfg, spec: ModelSpec, seed: int, seconds: float, trace: bool, devi
         pick = TR.rng(seed, 3).permutation(len(rest))[: int(mix["check"]["requests"]) - 1]
         sample = [longest] + [rest[i] for i in sorted(pick)]
     last = in_win[-int(mix["check"]["rank_steps"]):]
-    rank = [(rec.steps[i].live, rec.routed[i]) for i in last]
-    sampled = {o.uid for o in sample}
-    uids = sorted(sampled | {u for live, _ in rank for _, u, _ in live})
-    items = []
-    for u in uids:
-        prompt, toks = by_uid[u].prompt, np.asarray(served[u], np.int32)
-        items.append((u, prompt, toks, routing_of(rec, u, prompt.size, toks.size, spec.n_groups),
-                      u in sampled))
-    off = rows_off(rec, spec.batch_capacity(ecfg.batch_size))
+    prompts = {u: r.prompt for u, r in by_uid.items()}
+    reference = family.serve_check(spec, seed, rec, [o.uid for o in sample], last, prompts,
+                                   served, ecfg, None if control == "int8" else control)
     del engine, params, rec
     gc.collect()
 
-    nums = {k: float("nan") for k in
-            ("logit_gap_mean", "logit_gap_max", "token_flip_share", "route_margin_mean",
-             "route_margin_max", "decode_margin_mean", "decode_margin_max")}
     t_check = time.perf_counter()
-    if sample and rank and all(it[3] is not None for it in items):
-        nums = _reference_check(spec, seed, items, rank, ecfg.ctx, C, ring)
-    else:
-        log("check: no finished request or no decode step, or routing not fully recorded")
+    nums = reference()
     log(f"check: {len(sample)} requests sampled, {sum(len(served[o.uid]) for o in sample)} "
-        f"served tokens; {len(rank)} decode steps ranked over {len(items)} requests; "
-        f"reference {time.perf_counter() - t_check:.1f}s")
-    nums["decode_rows_off"] = off
+        f"served tokens; reference {time.perf_counter() - t_check:.1f}s")
     checks = held(nums, cell.checks)
     correct = bool(sample) and all(
         np.isfinite(c["value"]) and c["value"] <= c["limit"] for c in checks.values())

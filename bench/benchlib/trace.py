@@ -13,9 +13,12 @@ builds them from the ``.xplane.pb`` that ``jax.profiler`` writes.
 """
 from __future__ import annotations
 
+import bisect
 import collections
 import dataclasses
+import functools
 import glob
+import heapq
 import os
 import re
 import sys
@@ -44,6 +47,7 @@ def find_xplane(path: str) -> str:
     return found[-1]
 
 
+@functools.lru_cache(maxsize=None)
 def short_op(name: str) -> str:
     """``%fusion.12 = bf16[...] fusion(...), kind=kOutput`` -> ``fusion.12
     (fusion, kOutput)``: an XLA op event's instruction name and opcode."""
@@ -111,9 +115,15 @@ def self_times(events: Sequence[Interval]) -> List[Tuple[str, float, float, floa
     return [(n, s, e, own[i]) for i, (n, s, e) in enumerate(events)]
 
 
-def covered(busy: Sequence[Tuple[float, float]], lo: float, hi: float) -> float:
-    """Length of [lo, hi] that the merged intervals ``busy`` cover."""
-    return sum(max(0.0, min(e, hi) - max(s, lo)) for s, e in busy)
+def covered(busy: Sequence[Tuple[float, float]], lo: float, hi: float,
+            starts: Optional[Sequence[float]] = None) -> float:
+    """Length of [lo, hi] that the merged intervals ``busy`` cover. With
+    ``starts``, the intervals' start times, only those that can overlap
+    [lo, hi] are visited."""
+    i, j = 0, len(busy)
+    if starts is not None:
+        i, j = max(bisect.bisect_right(starts, lo) - 1, 0), bisect.bisect_left(starts, hi)
+    return sum(max(0.0, min(e, hi) - max(s, lo)) for s, e in busy[i:j])
 
 
 def idle_gaps(busy: Sequence[Tuple[float, float]], lo: float, hi: float) -> List[Tuple[float, float]]:
@@ -128,11 +138,30 @@ def idle_gaps(busy: Sequence[Tuple[float, float]], lo: float, hi: float) -> List
 
 
 def innermost(spans: Sequence[Interval], t: float) -> Optional[str]:
-    best = None
-    for name, s, e in spans:
-        if s <= t < e and (best is None or e - s < best[1]):
-            best = (name, e - s)
-    return None if best is None else best[0]
+    return innermost_each(spans, [t])[0]
+
+
+def innermost_each(spans: Sequence[Interval], times: Sequence[float]) -> List[Optional[str]]:
+    """For each of ``times``, the shortest span that holds it (``s <= t <
+    e``; the first listed among equals), in one sweep over the times in
+    order."""
+    out: List[Optional[str]] = [None] * len(times)
+    by_start = sorted(range(len(spans)), key=lambda i: spans[i][1])
+    ends: List[Tuple[float, int]] = []  # heap of the open spans' (end, index)
+    open_: set = set()
+    k = 0
+    for q in sorted(range(len(times)), key=times.__getitem__):
+        t = times[q]
+        while k < len(by_start) and spans[by_start[k]][1] <= t:
+            heapq.heappush(ends, (spans[by_start[k]][2], by_start[k]))
+            open_.add(by_start[k])
+            k += 1
+        while ends and ends[0][0] <= t:
+            open_.discard(heapq.heappop(ends)[1])
+        if open_:
+            best = min(open_, key=lambda i: (spans[i][2] - spans[i][1], i))
+            out[q] = spans[best][0]
+    return out
 
 
 def window_of(td: TraceData) -> Tuple[float, float]:
@@ -152,6 +181,11 @@ class Reduction:
     lo: float
     hi: float
 
+    @functools.cached_property
+    def starts(self) -> List[float]:
+        """Start times of device 0's busy intervals."""
+        return [s for s, _ in self.busy[0]]
+
     @property
     def idle_share(self) -> float:
         return 1.0 - self.busy_s / self.window_s
@@ -166,8 +200,10 @@ def reduce(td: TraceData, lo: Optional[float] = None, hi: Optional[float] = None
     busy = [merge(ops, lo, hi) for ops in td.device_ops]
     busy_s = sum(covered(b, lo, hi) for b in busy) / len(busy) / 1e9
     idle: Dict[str, float] = collections.defaultdict(float)
-    for gs, ge in idle_gaps(busy[0], lo, hi):
-        idle[innermost(td.host_spans, (gs + ge) / 2) or "outside any span"] += (ge - gs) / 1e9
+    gaps = idle_gaps(busy[0], lo, hi)
+    names = innermost_each(td.host_spans, [(gs + ge) / 2 for gs, ge in gaps])
+    for (gs, ge), name in zip(gaps, names):
+        idle[name or "outside any span"] += (ge - gs) / 1e9
     per_op: Dict[str, float] = collections.defaultdict(float)
     for name, s, e, own in self_times(td.device_ops[0]):
         if lo <= s < hi:
@@ -195,7 +231,7 @@ def span_idle_seconds(td: TraceData, red: Reduction, name: str) -> Tuple[float, 
         if sname != name or s < red.lo or e > red.hi:
             continue
         n += 1
-        tot += (e - s) - covered(red.busy[0], s, e)
+        tot += (e - s) - covered(red.busy[0], s, e, red.starts)
     return tot / 1e9, n
 
 

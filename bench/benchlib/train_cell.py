@@ -3,11 +3,16 @@
 Set-up builds one object, the program's jitted train step (``make_train_step``
 with the state donated, as ``launch/train.py`` builds it) with its state, and
 drives it from the seed through its first ``check_steps`` steps with the
-window's own call and feed; those steps compile it and give the readings the
-check compares. The window then goes on stepping the same object, with a new
-batch made on the host for every step. After the window the state is freed
-and the float32 reference (``reference.train_readings``) follows the same
-first steps:
+window's own call and feed; those steps compile it, time it and give the
+readings the check compares. The window then goes on stepping the same
+object, with a new batch made on the host for every step, keeping about
+``AHEAD_S`` seconds of steps dispatched ahead of the one whose loss it reads,
+so that a host that stands still for a moment does not leave the chip idle.
+When its time is up it sends nothing more, reads every loss it sent, and only
+then reads the clock: all the steps sent count, over all that time. After the
+window the state is freed and the model family's float32 reference (its
+``train_loss`` through ``reference.train_readings``) follows the same first
+steps:
 
 - ``loss_gap``: each step's loss against the reference's, relative;
 - ``grad_gap``: the clipped first gradient, recovered from the optimizer's
@@ -20,24 +25,26 @@ first steps:
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
 import gc
+import math
 import time
 from typing import Any, Callable, Dict, List, Optional
 
 import numpy as np
 
-from . import flops as FL
 from . import traffic as TR
 from .harness import log
-from .spec import ModelSpec
+
+AHEAD_S = 6.0  # seconds of steps in flight ahead of the loss the window reads
 
 
 @dataclasses.dataclass
 class TrainRun:
     """What the metric readers read of a training run."""
 
-    spec: ModelSpec
+    spec: Any
     window_s: float
     steps: int
     tokens: int
@@ -73,10 +80,11 @@ def compare(prog, ref) -> Dict[str, float]:
     }
 
 
-def run(cell, cfg, spec: ModelSpec, seed: int, seconds: float, trace: bool, devices,
+def run(cell, family, cfg, spec: Any, seed: int, seconds: float, trace: bool, devices,
         clock, trace_dir: str, control: Optional[str] = None,
         fault: Optional[Callable[[Callable], Callable]] = None):
-    """One training run: (result without ``checks``, checks, TrainRun, memory).
+    """One training run of ``family``'s model: (result without ``checks``,
+    checks, TrainRun, memory).
     ``control="fp8"`` holds the reference computed through float8 to the
     float32 one instead of the program (no window)."""
     import jax
@@ -106,8 +114,8 @@ def run(cell, cfg, spec: ModelSpec, seed: int, seconds: float, trace: bool, devi
     if control is not None:
         P0 = W.params_fn(spec, True)(W.seed_key(seed))
         host = [TR.train_batch(mix, seed, i, spec.vocab) for i in range(n_check)]
-        got = REF.train_readings(P0, spec, o, host, rows, precision=control)
-        ref = REF.train_readings(P0, spec, o, host, rows)
+        got = REF.train_readings(family.train_loss, P0, spec, o, host, rows, precision=control)
+        ref = REF.train_readings(family.train_loss, P0, spec, o, host, rows)
         nums = compare(got, ref)
         return ({"correct": False, "attempted": 0, "failed": 0, "readings": nums},
                 held(nums, limits), None, 0)
@@ -130,19 +138,26 @@ def run(cell, cfg, spec: ModelSpec, seed: int, seconds: float, trace: bool, devi
         if i == 0:
             g1 = jax.jit(lambda m: REF.leaf_norms(jax.tree.map(lambda x: x / (1.0 - b1), m)))(
                 state["opt"]["m"])
+            jax.block_until_ready(g1)
+            t_warm = time.perf_counter()
+    jax.block_until_ready(losses[-1])
+    step_s = (time.perf_counter() - t_warm) / max(n_check - 1, 1)
+    ahead = max(1, math.ceil(AHEAD_S / step_s))
     change = jax.jit(REF.leaf_change_norms)(state["params"], params0)
     prog = ([float(x) for x in losses], {k: float(v) for k, v in g1.items()},
             {k: float(v) for k, v in change.items()})
     del params0
     log(f"first {n_check} steps: losses {prog[0]}; compile {clock.seconds:.1f}s, "
-        f"{clock.compiles} compiles, cache hits {clock.hits} misses {clock.misses}")
+        f"{clock.compiles} compiles, cache hits {clock.hits} misses {clock.misses}; "
+        f"{step_s:.3f}s a step, {ahead} in flight")
 
     if trace:
         opts = jax.profiler.ProfileOptions()
         opts.python_tracer_level = 0
         jax.profiler.start_trace(trace_dir, profiler_options=opts)
     compiles0 = clock.compiles
-    i, prev, failed, n = n_check, None, 0, 0
+    i, failed, n = n_check, 0, 0
+    pending: collections.deque = collections.deque()  # losses sent and not yet read
     with span(trace, "bench.window"):
         t_open = time.perf_counter()
         while time.perf_counter() - t_open < seconds:
@@ -150,15 +165,15 @@ def run(cell, cfg, spec: ModelSpec, seed: int, seconds: float, trace: bool, devi
                 b = batch(i)
             with span(trace, "train_step.dispatch"):
                 state, metrics = step(state, b)
-            if prev is not None:
-                with span(trace, "loss.wait"):
-                    failed += int(not np.isfinite(float(prev)))
-            prev = metrics["loss"]
+            pending.append(metrics["loss"])
             i += 1
             n += 1
-        if prev is not None:
-            with span(trace, "loss.wait"):
-                failed += int(not np.isfinite(float(prev)))
+            while len(pending) > ahead:
+                with span(trace, "loss.wait"):
+                    failed += int(not np.isfinite(float(pending.popleft())))
+        with span(trace, "loss.wait"):
+            while pending:
+                failed += int(not np.isfinite(float(pending.popleft())))
         t_close = time.perf_counter()
     if trace:
         jax.profiler.stop_trace()
@@ -166,15 +181,15 @@ def run(cell, cfg, spec: ModelSpec, seed: int, seconds: float, trace: bool, devi
     memory = max((d.memory_stats() or {}).get("peak_bytes_in_use", 0) for d in devices)
     log(f"memory_stats after the window: {devices[0].memory_stats()}")
     log(f"window: {window_s:.3f}s, {n} steps; compiles in window {clock.compiles - compiles0}")
-    record = TrainRun(spec, window_s, n, n * B * S, FL.train_step_flops(spec, B, S),
+    record = TrainRun(spec, window_s, n, n * B * S, family.train_step_flops(spec, B, S),
                       t_open=t_open)
-    del state, prev, step
+    del state, metrics, step
     gc.collect()
 
     t_check = time.perf_counter()
     P0 = W.params_fn(spec, True)(W.seed_key(seed))
     host = [TR.train_batch(mix, seed, j, spec.vocab) for j in range(n_check)]
-    ref = REF.train_readings(P0, spec, o, host, rows)
+    ref = REF.train_readings(family.train_loss, P0, spec, o, host, rows)
     del P0
     log(f"check: reference {n_check} steps in {time.perf_counter() - t_check:.1f}s")
     nums = compare(prog, ref)

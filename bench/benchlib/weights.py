@@ -1,59 +1,22 @@
 """Random weights from the seed, in the layout the program serves and trains.
 
 The benchmark makes the weights itself, so the reference can make the very
-same values without taking anything from the program. Each leaf is a normal
-draw (a leaf's own key folded from the seed's key and the leaf's index) times
-``1/sqrt(fan_in)``; norm scales are ones and biases zeros. Matrices and norms
-are stored in the served dtype, the MoD router and predictor in float32, as
-the program keeps them.
+same values without taking anything from the program. The model family
+lists the leaves (``spec.leaves()``: path, shape, storage dtype, kind,
+fan-in, in a fixed order). Each leaf is a normal draw (a leaf's own key
+folded from the seed's key and the leaf's index) times ``1/sqrt(fan_in)``;
+norm scales are ones and biases zeros, each stored in its listed dtype.
 """
 from __future__ import annotations
 
 import functools
-from typing import Any, Dict, List, Tuple
+from typing import Any, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
 
-from .spec import ModelSpec
-
 # (path, shape, storage dtype, kind, fan_in); kind is "normal", "ones" or "zeros"
 Leaf = Tuple[Tuple[str, ...], Tuple[int, ...], str, str, int]
-
-
-def _block(prefix: Tuple[str, ...], s: ModelSpec, dt: str) -> List[Leaf]:
-    G, D, F = s.n_groups, s.d_model, s.d_ff
-    q, kv = s.n_heads * s.head_dim, s.n_kv_heads * s.head_dim
-    return [
-        (prefix + ("attn", "wk"), (G, D, kv), dt, "normal", D),
-        (prefix + ("attn", "wo"), (G, q, D), dt, "normal", q),
-        (prefix + ("attn", "wq"), (G, D, q), dt, "normal", D),
-        (prefix + ("attn", "wv"), (G, D, kv), dt, "normal", D),
-        (prefix + ("ln1", "scale"), (G, D), dt, "ones", 1),
-        (prefix + ("ln2", "scale"), (G, D), dt, "ones", 1),
-        (prefix + ("mlp", "w_down"), (G, F, D), dt, "normal", F),
-        (prefix + ("mlp", "w_gate"), (G, D, F), dt, "normal", D),
-        (prefix + ("mlp", "w_up"), (G, D, F), dt, "normal", D),
-    ]
-
-
-def leaves(s: ModelSpec) -> List[Leaf]:
-    """Every parameter, in a fixed order (the order sets each leaf's key)."""
-    dt, G, D, Hp = s.dtype, s.n_groups, s.d_model, s.predictor_hidden
-    out: List[Leaf] = [
-        (("embed", "tok"), (s.vocab, D), dt, "normal", 1),
-        (("embed", "unemb"), (D, s.vocab), dt, "normal", D),
-        (("final_norm", "scale"), (D,), dt, "ones", 1),
-    ]
-    out += _block(("groups", "full"), s, dt)
-    out += _block(("groups", "mod", "block"), s, dt)
-    out += [
-        (("groups", "mod", "predictor", "b1"), (G, Hp), "float32", "zeros", 1),
-        (("groups", "mod", "predictor", "w1"), (G, D, Hp), "float32", "normal", D),
-        (("groups", "mod", "predictor", "w2"), (G, Hp), "float32", "normal", Hp),
-        (("groups", "mod", "router", "w"), (G, D), "float32", "normal", D),
-    ]
-    return out
 
 
 def seed_key(seed: int) -> jax.Array:
@@ -85,12 +48,12 @@ def round_to(x: jax.Array, dtype: str) -> jax.Array:
     return jax.lax.bitcast_convert_type(b, jnp.float32)
 
 
-def make_params(s: ModelSpec, key: jax.Array, as_float32: bool = False) -> Dict[str, Any]:
+def make_params(s: Any, key: jax.Array, as_float32: bool = False) -> Dict[str, Any]:
     """The weights for ``key`` (trace under ``jax.jit``: one call on the
     device). ``as_float32`` widens the stored values to float32 exactly, for
     the reference."""
     flat = {}
-    for i, (path, shape, dtype, kind, fan_in) in enumerate(leaves(s)):
+    for i, (path, shape, dtype, kind, fan_in) in enumerate(s.leaves()):
         if kind == "ones":
             v = jnp.ones(shape, jnp.float32)
         elif kind == "zeros":
@@ -103,15 +66,15 @@ def make_params(s: ModelSpec, key: jax.Array, as_float32: bool = False) -> Dict[
 
 
 @functools.lru_cache(maxsize=None)
-def params_fn(s: ModelSpec, as_float32: bool):
+def params_fn(s: Any, as_float32: bool):
     """``key -> params``, jitted once per process."""
     return jax.jit(lambda key: make_params(s, key, as_float32))
 
 
-def param_bytes(s: ModelSpec) -> Dict[Tuple[str, ...], int]:
+def param_bytes(s: Any) -> Dict[Tuple[str, ...], int]:
     """Stored bytes of every leaf."""
     out = {}
-    for path, shape, dtype, _, _ in leaves(s):
+    for path, shape, dtype, _, _ in s.leaves():
         n = 1
         for d in shape:
             n *= d

@@ -52,14 +52,16 @@ def measure(cell, seed: int, seconds: float, trace: bool, devices, root: Path = 
     clock = H.CompileClock()
     if cache:
         H.enable_compile_cache(root)
-    cfg, spec = program.model_config(cell.config, strict=strict)
+    family = H.family(cell.family, root)
+    spec = family.spec(cell.config)
+    cfg = family.program_config(cell.config, strict=strict)
     program.check_layout(cfg, spec)
     peaks = peaks_for(devices[0].device_kind) if devices[0].platform == "tpu" else None
     trace_dir = root / ".bench_trace" / cell.name
     shutil.rmtree(trace_dir, ignore_errors=True)
     runner = {"serve": serve_cell, "train": train_cell}[cell.traffic["kind"]]
-    result, checks, rec, memory = runner.run(cell, cfg, spec, seed, seconds, trace, devices,
-                                             clock, str(trace_dir), control=control,
+    result, checks, rec, memory = runner.run(cell, family, cfg, spec, seed, seconds, trace,
+                                             devices, clock, str(trace_dir), control=control,
                                              fault=fault)
     device = {"platform": devices[0].platform, "kind": devices[0].device_kind,
               "count": len(devices), "memory_peak_bytes": int(memory)}
@@ -71,6 +73,7 @@ def measure(cell, seed: int, seconds: float, trace: bool, devices, root: Path = 
                 metrics[m["name"]] = {"value": v, "unit": m["unit"]}
     breakdown = None
     if rec is not None and trace:
+        t_trace = time.perf_counter()
         rec.td = load(str(trace_dir))
         rec.red = reduce(rec.td)
         rec.peaks = peaks
@@ -83,6 +86,7 @@ def measure(cell, seed: int, seconds: float, trace: bool, devices, root: Path = 
         breakdown = {"device_ops": [list(x) for x in rec.red.top_ops],
                      "idle_gaps": [list(x) for x in rec.red.idle_by_span]}
         shutil.rmtree(trace_dir, ignore_errors=True)
+        H.log(f"trace: read and reduced in {time.perf_counter() - t_trace:.1f}s")
     out = dict(result, metrics=metrics, device=device)
     if breakdown is not None:
         out["breakdown"] = breakdown

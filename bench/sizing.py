@@ -28,7 +28,7 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 from jax.sharding import SingleDeviceSharding  # noqa: E402
 
-from benchlib import program  # noqa: E402
+from benchlib import harness  # noqa: E402
 
 GB = 1e9
 HBM = 16 * 2**30  # one TPU v5e
@@ -49,13 +49,17 @@ def _report(name: str, compiled) -> float:
     return total
 
 
+def _program_config(conf):
+    return harness.family(conf.get("family", harness.DEFAULT_FAMILY)).program_config(conf)
+
+
 def serve_programs(conf, slots: int, sh) -> float:
     """The paged decode step and the prefill chunk, as the engine builds them
     (``repro.serve.engine``: ``_build_step_fn`` and ``_make_chunk``)."""
     from repro.models import api
     from repro.serve import cache as CA
 
-    cfg, _ = program.model_config(conf)
+    cfg = _program_config(conf)
     e = conf["engine"]
     ctx, page, chunk = int(e["ctx"]), int(e["page_size"]), int(e["prefill_chunk"])
     full = api.make_caches(cfg, slots, ctx, specs=True)
@@ -104,7 +108,7 @@ def train_program(conf, mix, batch: int, sh) -> float:
     from repro.config import OptimConfig, TrainConfig
     from repro.train.loop import make_train_step, train_state_specs
 
-    cfg, _ = program.model_config(conf)
+    cfg = _program_config(conf)
     S = int(mix["seq_len"])
     tcfg = TrainConfig(global_batch=batch, seq_len=S, optim=OptimConfig())
     state = _specs(train_state_specs(jax.random.PRNGKey(0), cfg), sh)

@@ -34,7 +34,7 @@ def run(workload: str, seed: int, seconds: float, trace: int) -> dict:
     out.update(seed=seed, trace=trace, rc=p.returncode, wall_s=time.perf_counter() - t,
                log=[x for x in p.stderr.splitlines()
                     if x.startswith(("[bench] warm-up", "[bench] first", "[bench] window",
-                                     "[bench] check:", "[bench] memory_stats"))])
+                                     "[bench] pool", "[bench] check:", "[bench] memory_stats"))])
     if p.returncode or not out.get("correct"):
         sys.stderr.write(p.stderr[-4000:])
     return out

@@ -9,8 +9,10 @@ import pytest
 
 import run as R
 import tiny
-from benchlib import serve_cell as SC
-from readings import reversed_ranking
+from benchlib import harness as H
+
+MT = H.family("mod_transformer")
+reversed_ranking = MT.reversed_ranking
 
 SEED = 3_000_000_023
 LIMITS = {"logit_gap_mean": 3e-4, "route_margin_mean": 0.05, "decode_margin_mean": 0.05,
@@ -23,21 +25,10 @@ def measure(**kw):
     return R.measure(cell, SEED, 1.0, False, jax.devices()[:1], strict=False, cache=False, **kw)
 
 
-def altered(engine):
-    """The sixth token of every request, plus one."""
-    sample, vocab = engine._sample, engine.cfg.vocab
-    engine._sample = lambda req, row, i: (sample(req, row, i) + (i == 5)) % vocab
-
-
-def unseen(engine):
-    """Decode through the engine's own step, past the recorder."""
-    engine._step_fn = engine._level_fns[0]
-
-
 CASES = {"program": ({}, None), "control": ({"control": "int8"}, "logit_gap_mean"),
-         "token_altered": ({"fault": altered}, "logit_gap_mean"),
+         "token_altered": ({"fault": tiny.altered}, "logit_gap_mean"),
          "reversed_ranking": ({"fault": reversed_ranking}, "decode_margin_mean"),
-         "step_unseen": ({"fault": unseen}, "decode_rows_off")}
+         "step_unseen": ({"fault": tiny.unseen}, "decode_rows_off")}
 
 
 @pytest.mark.parametrize("case", sorted(CASES))
@@ -60,8 +51,8 @@ def test_decode_margin_is_zero_for_the_top_rows_and_grows_when_reversed():
     live = [(0, 7, 1), (1, 7, 2), (2, 7, 3), (3, 8, 3)]  # live scores 3, 1, 2, 4
     top = np.array([[1, 0, 0, 1]])
     low = np.array([[0, 1, 1, 0]])
-    assert SC.decode_margins([(live, top)], scores).tolist() == [0.0]
-    (m,) = SC.decode_margins([(live, low)], scores)
+    assert MT.decode_margins([(live, top)], scores).tolist() == [0.0]
+    (m,) = MT.decode_margins([(live, low)], scores)
     assert np.isclose(m, (4 - 1) / np.std([3, 1, 2, 4]))
     # a block that routed every live row ranks nothing
-    assert SC.decode_margins([(live, np.ones((1, 4)))], scores).size == 0
+    assert MT.decode_margins([(live, np.ones((1, 4)))], scores).size == 0

@@ -1,11 +1,15 @@
 """The trace reduction on a small synthetic trace: busy union, idle share,
 idle gaps named by the innermost host span, self time of nested ops."""
+import random
+
 import pytest
 
 from benchlib.trace import (
     TraceData,
+    covered,
     idle_gaps,
     innermost,
+    innermost_each,
     merge,
     module_seconds,
     reduce,
@@ -85,3 +89,27 @@ def test_a_trace_without_device_ops_is_refused():
     td.device_ops = [[]]
     with pytest.raises(ValueError):
         reduce(td)
+
+
+def test_the_sweeps_read_what_the_plain_loops_read():
+    """The innermost span of many times in one sweep, and the busy time of a
+    span found by bisection, against the plain loops over every span and
+    every interval."""
+    def innermost_loop(spans, t):
+        best = None
+        for name, s, e in spans:
+            if s <= t < e and (best is None or e - s < best[1]):
+                best = (name, e - s)
+        return None if best is None else best[0]
+
+    rng = random.Random(5)
+    spans = [(f"s{i}", float(s), float(s + rng.choice([1, 2, 5, 5, 30]))) for i, s in
+             enumerate(rng.randrange(0, 200) for _ in range(300))]
+    times = [rng.uniform(-5, 240) for _ in range(500)] + [s for _, s, _ in spans[:50]]
+    assert innermost_each(spans, times) == [innermost_loop(spans, t) for t in times]
+    busy = merge(spans, 0.0, 230.0)
+    starts = [s for s, _ in busy]
+    for _ in range(200):
+        lo = rng.uniform(-5, 240)
+        hi = lo + rng.uniform(0, 40)
+        assert covered(busy, lo, hi, starts) == covered(busy, lo, hi)
