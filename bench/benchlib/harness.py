@@ -14,7 +14,10 @@ and entries, never by editing this one:
 - the traffic mix: ``bench/traffic/<traffic>.json`` (its ``kind`` picks the
   runner: ``serve`` or ``train``);
 - each metric: ``bench/metrics/<name>.py``, a reader with ``read(run)``;
-- the limits of the cell's check: ``bench/checks/<workload>.json``.
+- the limits of the cell's check: ``bench/checks/<workload>.json``;
+- the chips: a serving configuration's ``engine.mesh`` (``{"data": d,
+  "model": m}``) serves the cell from one engine over a mesh of ``d * m``
+  chips, which must be the workload's ``chips``; without it, one chip.
 """
 from __future__ import annotations
 
@@ -53,6 +56,12 @@ class Cell:
     def family(self) -> str:
         return self.config.get("family", DEFAULT_FAMILY)
 
+    @property
+    def chips(self) -> int:
+        """The chips the configuration's mesh spans (1 without one)."""
+        mesh = self.config.get("engine", {}).get("mesh") or {"data": 1, "model": 1}
+        return int(mesh["data"]) * int(mesh["model"])
+
 
 def _reports(metric: Dict[str, Any], cell: str) -> bool:
     return "workloads" not in metric or cell in metric["workloads"]
@@ -72,7 +81,11 @@ def resolve(name: str, root: Path = ROOT) -> Cell:
     per_layer = [m for m in bench["per_layer"]
                  if m["moves"] in moved and _reports(m, name)]
     checks = json.loads((root / "bench" / "checks" / f"{name}.json").read_text())["limits"]
-    return Cell(w, config, traffic, e2e, per_layer, checks)
+    cell = Cell(w, config, traffic, e2e, per_layer, checks)
+    if cell.chips != int(w["chips"]):
+        raise ValueError(f"workload {name!r} asks for {w['chips']} chips, but its "
+                         f"configuration's engine mesh spans {cell.chips}")
+    return cell
 
 
 def reader(metric: str, root: Path = ROOT) -> Callable[[Any], Optional[float]]:

@@ -16,11 +16,18 @@ PEAKS: Dict[str, Dict[str, object]] = {
 }
 
 
-def peaks_for(device_kind: str) -> Dict[str, object]:
+SUMMED = ("bf16_flops_per_s", "hbm_bytes_per_s", "hbm_bytes")  # a cell's chips add these
+
+
+def peaks_for(device_kind: str, chips: int = 1) -> Dict[str, object]:
+    """The peaks of ``chips`` chips of ``device_kind`` together: one chip's
+    times ``chips``, so that a share of them is a share of every chip a cell
+    holds."""
     try:
-        return PEAKS[device_kind]
+        one = PEAKS[device_kind]
     except KeyError:
         raise KeyError(
             f"no published peaks for device kind {device_kind!r}; "
             f"known: {sorted(PEAKS)}"
         ) from None
+    return dict(one, **{k: chips * one[k] for k in SUMMED})

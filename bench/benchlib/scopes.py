@@ -12,11 +12,13 @@ schema is imported).
 
 A scope's time in a program is the self time (``trace.self_times``) of the
 device-0 ops that run inside that program's executions and inside the
-window and whose path holds the scope, per execution of the program. A
-program compiled without the scopes (an older tree, or an executable loaded
-from a compile cache keyed without them) holds none of the names: its
-readers return None. A reader's first look at a program logs its whole
-split: device seconds by innermost scope, and those under none.
+window and whose path holds the scope, per execution of the program. On a
+mesh every device runs the same program on its own shard, and device 0
+stands for them all. A program compiled without the scopes (an older tree,
+or an executable loaded from a compile cache keyed without them) holds none
+of the names: its readers return None. A reader's first look at a program
+logs its whole split: device seconds by innermost scope, and those under
+none.
 """
 from __future__ import annotations
 

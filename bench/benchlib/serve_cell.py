@@ -16,6 +16,13 @@ keeps more of each call through the ``keep_*`` hooks (small arrays: nothing is
 copied and nothing waits for the device). An engine step whose slots decoded
 through a call the recorder did not see is counted (``unrecorded``); every
 family's check fails on it.
+
+A configuration whose ``engine`` names a ``mesh`` (``{"data": d, "model":
+m}``, as many chips as its workload) is served by one engine over a
+``("data", "model")`` mesh of the cell's own devices: the weights are made
+on it, each leaf with the sharding the program gives it, so the engine places
+nothing anew and no device holds the whole model; the family's reference
+runs on the same mesh after the engine is freed.
 """
 from __future__ import annotations
 
@@ -28,6 +35,7 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 import numpy as np
 
 from . import traffic as TR
+from . import weights as W
 from .harness import log
 
 
@@ -147,6 +155,34 @@ class ServeRun:
     t_open: float = 0.0  # window open, on time.perf_counter
 
 
+def mesh_of(eng_conf: Dict[str, Any], devices):
+    """The ``("data", "model")`` mesh the configuration names over the cell's
+    devices, or None where it names none."""
+    shape = eng_conf.get("mesh")
+    if shape is None:
+        return None
+    from jax.sharding import AxisType, Mesh
+
+    grid = np.asarray(devices).reshape(int(shape["data"]), int(shape["model"]))
+    return Mesh(grid, ("data", "model"), axis_types=(AxisType.Auto,) * 2)
+
+
+def program_shardings(spec: Any, mesh) -> Tuple[Any, ...]:
+    """Per leaf of ``spec.leaves()``, the sharding the engine places it with
+    on ``mesh`` (``repro.distributed.sharding.param_shardings``)."""
+    import jax
+
+    from repro.config import MeshConfig
+    from repro.distributed.sharding import param_shardings
+    from repro.utils import flatten_dict
+
+    leaves = spec.leaves()
+    shapes = W.nest({p: jax.ShapeDtypeStruct(shape, dt) for p, shape, dt, _, _ in leaves})
+    mcfg = MeshConfig(pod=1, data=mesh.shape["data"], model=mesh.shape["model"], fsdp=False)
+    flat = flatten_dict(param_shardings(shapes, mesh, mcfg))
+    return tuple(flat["/".join(p)] for p, _, _, _, _ in leaves)
+
+
 def run(cell, family, cfg, spec: Any, seed: int, seconds: float, trace: bool, devices,
         clock, trace_dir: str, control: Optional[str] = None,
         fault: Optional[Callable[[Any], None]] = None):
@@ -161,14 +197,16 @@ def run(cell, family, cfg, spec: Any, seed: int, seconds: float, trace: bool, de
     from repro.serve import EngineConfig, Request, ServingEngine
     from repro.serve.quant import QuantConfig
 
-    from . import weights as W
-
     eng_conf, mix = cell.config["engine"], cell.traffic
-    params = W.params_fn(spec, False)(W.seed_key(seed))
+    mesh = mesh_of(eng_conf, devices)
+    shardings = None if mesh is None else program_shardings(spec, mesh)
+    params = W.params_fn(spec, False, shardings)(W.seed_key(seed))
+    page_size = eng_conf["page_size"]
     ecfg = EngineConfig(
         batch_size=int(eng_conf["slots"]), ctx=int(eng_conf["ctx"]),
-        page_size=int(eng_conf["page_size"]), prefill_chunk=int(eng_conf["prefill_chunk"]),
-        policy=eng_conf["policy"], n_pages=eng_conf.get("n_pages"),
+        page_size=None if page_size is None else int(page_size),
+        prefill_chunk=int(eng_conf["prefill_chunk"]), policy=eng_conf["policy"],
+        n_pages=eng_conf.get("n_pages"), mesh=mesh,
         quant=QuantConfig(kv="int8", weights="int8") if control == "int8" else QuantConfig(),
     )
     engine = ServingEngine(params, cfg, engine=ecfg)
@@ -193,7 +231,7 @@ def run(cell, family, cfg, spec: Any, seed: int, seconds: float, trace: bool, de
     warm_uids = {u for u, r in by_uid.items() if r.warmup}
     while not warm_uids <= {o.uid for o in engine.finished}:
         engine.step()
-    jax.block_until_ready(engine.pool.pages)
+    jax.block_until_ready(engine.pool.pages if ecfg.page_size is not None else engine.pool.caches)
     log(f"warm-up: {engine.step_count} steps; compile {clock.seconds:.1f}s, "
         f"{clock.compiles} compiles, cache hits {clock.hits} misses {clock.misses}")
 

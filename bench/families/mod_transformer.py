@@ -435,19 +435,24 @@ def train_loss(P, s: Spec, tokens, labels, precision: str = "f32"):
 class RingRecorder(SC.Recorder):
     """Also keeps references to the routed rings' positions after each
     prefill chunk and to their cursors around each decode step, read from
-    the pool through its own public description, and the rows each decode
-    step reports routed (its ``mod/decode_routed`` aux: per row, the share of
-    routed blocks that took it). From these the check learns which tokens
-    each routed block ran on, and the counts learn the routed rows."""
+    the pool (the paged one through its own public description), and the
+    rows each decode step reports routed (its ``mod/decode_routed`` aux: per
+    row, the share of routed blocks that took it). From these the check
+    learns which tokens each routed block ran on, and the counts learn the
+    routed rows."""
 
     def __init__(self, engine: Any, cfg: Any, s: Spec, chunk: int, spans: bool):
         from repro.models import api
 
         super().__init__(engine, chunk, spans)
-        paths = [jax.tree_util.keystr(p) for p, _ in jax.tree_util.tree_flatten_with_path(
-            api.make_caches(cfg, engine.batch_size, engine.ctx, specs=True))[0]]
-        j = engine.pool.step_spec().resid_ids.index(paths.index("['groups']['mod']['cursor']"))
-        self._cursor = lambda: engine.pool.resid[j]
+        if engine.engine_config.page_size is None:  # the contiguous pool
+            self._cursor = lambda: engine.pool.caches["groups"]["mod"]["cursor"]
+        else:
+            paths = [jax.tree_util.keystr(p) for p, _ in jax.tree_util.tree_flatten_with_path(
+                api.make_caches(cfg, engine.batch_size, engine.ctx, specs=True))[0]]
+            j = engine.pool.step_spec().resid_ids.index(
+                paths.index("['groups']['mod']['cursor']"))
+            self._cursor = lambda: engine.pool.resid[j]
         self.ring = s.capacity(engine.ctx)
 
     def keep_chunk(self, out):
@@ -545,7 +550,7 @@ def _gaps_fn(s: Spec, n_chunks: int, ring: int, precision: str = "f32"):
 
 
 def _reference_check(s: Spec, seed: int, items, rank, ctx: int, C: int, ring: int,
-                     control: Optional[str] = None):
+                     control: Optional[str] = None, mesh: Any = None):
     """Runs the reference once over each of ``items`` (``(uid, prompt, served
     tokens, routing, sampled)``). Over the sampled requests' served tokens: the
     mean and the widest gap of a served token's logit below the reference's
@@ -554,8 +559,9 @@ def _reference_check(s: Spec, seed: int, items, rank, ctx: int, C: int, ring: in
     widest route margin; over the steps of ``rank``: the mean and the widest
     decode margin (``decode_margins``). With ``control`` (``"fp8"``), the
     tokens that the reference computed in that precision puts first at each
-    served position stand in for the served ones."""
-    P = W.params_fn(s, True)(W.seed_key(seed))
+    served position stand in for the served ones. On ``mesh`` (the engine's,
+    where it has one) the weights are made split over its devices."""
+    P = W.params_fn(s, True, None if mesh is None else W.spread(s, mesh))(W.seed_key(seed))
     fn = _gaps_fn(s, ctx // C, ring)
     lower = control and _gaps_fn(s, ctx // C, ring, control)
     gaps, margins, scores = [], [], {}
@@ -631,7 +637,7 @@ def serve_check(s: Spec, seed: int, rec: SC.Recorder, sampled: List[int], last: 
             log("check: no finished request or no decode step, or routing not fully recorded")
         else:
             nums = _reference_check(s, seed, items, rank, ecfg.ctx, ecfg.prefill_chunk, ring,
-                                    control)
+                                    control, ecfg.mesh)
         if s.routed:
             log(f"check: {len(rank)} decode steps ranked over {len(items)} requests")
         nums["decode_rows_off"] = off

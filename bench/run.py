@@ -56,7 +56,8 @@ def measure(cell, seed: int, seconds: float, trace: bool, devices, root: Path = 
     spec = family.spec(cell.config)
     cfg = family.program_config(cell.config, strict=strict)
     program.check_layout(cfg, spec)
-    peaks = peaks_for(devices[0].device_kind) if devices[0].platform == "tpu" else None
+    peaks = (peaks_for(devices[0].device_kind, len(devices))
+             if devices[0].platform == "tpu" else None)
     trace_dir = root / ".bench_trace" / cell.name
     shutil.rmtree(trace_dir, ignore_errors=True)
     runner = {"serve": serve_cell, "train": train_cell}[cell.traffic["kind"]]
