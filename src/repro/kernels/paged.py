@@ -25,8 +25,9 @@ in ``kernels/ops.py`` fold arbitrary lead/tail dims into F.
 ``paged_decode_attention`` is the padded decode step's attention read: one
 query per slot against that slot's K/V, read through the page table straight
 out of the layer-stacked pool (its ``(L, N, nkv, p, hd)`` view) and only up
-to the slot's last live page. Its oracle is the XLA formulation in
-``models/paged_kv.py`` (gather by the table, then ``attend``).
+to the slot's last live page, which it copies by hand in blocks of 128 keys.
+Its oracle is the XLA formulation in ``models/paged_kv.py`` (gather by the
+table, then ``attend``).
 """
 from __future__ import annotations
 
@@ -214,10 +215,160 @@ def paged_scatter_rows_pallas(
 # Live-page decode attention over the layer-stacked pool
 # ---------------------------------------------------------------------------
 
-# lanes of the pos block: a page's positions are a column of the (p, N) view
-# of the pos pages, fetched as the aligned 128-page block holding it
-_POS_LANES = 128
 NEG_INF = -1e30  # kernels/flash_attention.NEG_INF, the masked-score value
+# positions are read as aligned 128-lane windows of the (p, N) plane, the
+# window holding page n's column being n // 128
+_POS_LANES = 128
+# the layer's whole position plane stays in VMEM for the call while one
+# buffer of it fits this; a larger pool takes the per-page read
+_POS_PLANE_BYTES = 4 * 1024 * 1024
+
+
+def _split3(e: jax.Array) -> jax.Array:
+    """f32 ``e`` as three bf16 parts stacked on axis 1, whose sum is ``e`` to
+    2**-27 relative: a product with bf16 values is then exact in f32."""
+    hi = e.astype(jnp.bfloat16)
+    r = e - hi.astype(jnp.float32)
+    mid = r.astype(jnp.bfloat16)
+    lo = (r - mid.astype(jnp.float32)).astype(jnp.bfloat16)
+    return jnp.concatenate([hi, mid, lo], axis=1)
+
+
+def _live_decode_kernel(
+    tbl_ref,  # (B, P) scalar-prefetch page table
+    lay_ref,  # (1,) scalar-prefetch layer of the stack
+    npg_ref,  # (B,) scalar-prefetch live pages per slot
+    qpos_ref,  # (B,) scalar-prefetch query positions
+    q_ref,  # (nkv, g, hd) this slot's query heads, grouped by kv head
+    k_hbm,  # (L, N, nkv, p, hd) in HBM
+    v_hbm,
+    pos_ref,  # (p, W) the layer's position plane, in VMEM for the call
+    o_ref,  # (nkv, g, hd)
+    kbuf,  # (2, nkv, ppb * p, hd) two-deep K/V buffers
+    vbuf,
+    sem,  # (2,) DMA semaphores, one per buffer
+    nxt,  # (1,) SMEM: the buffer the next slot's first block lands in
+    *,
+    scale: float,
+    causal: bool,
+    window: int,
+    ppb: int,
+):
+    """One slot: a loop over its live blocks of ``ppb`` pages. Each live
+    page's K and V are copied into a two-deep VMEM buffer, the next block's
+    copies (the next slot's first, after a slot's last) start before this
+    block's compute, and the online softmax is updated once per block over
+    its ``ppb * p`` keys, on the lanes."""
+    b = pl.program_id(0)
+    n_slots = pl.num_programs(0)
+    lay = lay_ref[0]
+    nkv, g, hd = q_ref.shape
+    p = k_hbm.shape[3]
+    K = ppb * p
+
+    def page(s, lp):
+        # logical page lp of slot s, clamped to its last live page so that
+        # the table is read in bounds
+        return tbl_ref[s, jnp.minimum(lp, npg_ref[s] - 1)]
+
+    def fetch(s, j, buf, start):
+        """Start (or wait for) the copies of slot ``s``'s block ``j``."""
+        for i in range(ppb):
+            lp = j * ppb + i
+            pid = page(s, lp)
+            dst = (buf, slice(None), pl.ds(i * p, p))
+            copies = [
+                pltpu.make_async_copy(k_hbm.at[lay, pid], kbuf.at[dst], sem.at[buf]),
+                pltpu.make_async_copy(v_hbm.at[lay, pid], vbuf.at[dst], sem.at[buf]),
+            ]
+
+            @pl.when(lp < npg_ref[s])
+            def _kv(copies=copies):
+                for c in copies:
+                    c.start() if start else c.wait()
+
+    @pl.when(b == 0)
+    def _first():
+        # dead pages of a block keep what the buffer held: make it finite
+        kbuf[...] = jnp.zeros_like(kbuf)
+        vbuf[...] = jnp.zeros_like(vbuf)
+        nxt[0] = 0
+        fetch(0, 0, 0, True)
+
+    s0 = nxt[0]
+    npg = npg_ref[b]
+    qp = qpos_ref[b]
+    n = (npg + ppb - 1) // ppb
+    q = q_ref[...]
+    f32 = q.dtype == jnp.float32
+    precision = jax.lax.Precision.HIGHEST if f32 else None
+    lanes = jax.lax.broadcasted_iota(jnp.int32, (p, _POS_LANES), 1)
+    rows = jax.lax.broadcasted_iota(jnp.int32, (p, K), 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, (p, K), 1)
+    keys = jax.lax.broadcasted_iota(jnp.int32, (1, K), 1)
+
+    def positions(j):
+        """(1, K) stored positions of the block's keys: page i's column of
+        the plane lands on lanes ``i * p ..``."""
+        w = jnp.zeros((p, K), jnp.int32)
+        for i in range(ppb):
+            pid = page(b, j * ppb + i)
+            win = pl.multiple_of((pid // _POS_LANES) * _POS_LANES, _POS_LANES)
+            chunk = pos_ref[:, pl.ds(win, _POS_LANES)]
+            col = jnp.sum(jnp.where(lanes == pid % _POS_LANES, chunk, 0), axis=1, keepdims=True)
+            w = jnp.where(cols == rows + i * p, col, w)
+        return jnp.sum(w, axis=0, keepdims=True)
+
+    def body(j, carry):
+        m, l, acc = carry
+        buf = (s0 + j) % 2
+
+        @pl.when(j + 1 < n)
+        def _next_block():
+            fetch(b, j + 1, 1 - buf, True)
+
+        @pl.when((j + 1 == n) & (b + 1 < n_slots))
+        def _next_slot():
+            fetch(b + 1, 0, 1 - buf, True)
+
+        fetch(b, j, buf, False)
+        kp = positions(j)
+        valid = (kp >= 0) & (keys < (npg - j * ppb) * p)
+        if causal:
+            valid &= kp <= qp
+        if window > 0:
+            valid &= qp - kp < window
+        valid = valid[None]  # (1, 1, K)
+        k = kbuf[buf].astype(q.dtype)  # (nkv, K, hd)
+        s = jax.lax.dot_general(
+            q, k, (((2,), (2,)), ((0,), (0,))), precision=precision,
+            preferred_element_type=jnp.float32) * scale  # (nkv, g, K)
+        s = jnp.where(valid, s, NEG_INF)
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        m_safe = jnp.where(m_new > NEG_INF / 2, m_new, 0.0)
+        e = jnp.where(valid, jnp.exp(s - m_safe), 0.0)
+        corr = jnp.where(m > NEG_INF / 2, jnp.exp(m - m_safe), 0.0)
+        v = vbuf[buf]
+        if v.dtype == jnp.bfloat16:
+            pv = jax.lax.dot_general(
+                _split3(e), v, (((2,), (1,)), ((0,), (0,))),
+                preferred_element_type=jnp.float32)  # (nkv, 3g, hd)
+            pv = pv[:, :g] + pv[:, g : 2 * g] + pv[:, 2 * g :]
+        else:
+            pv = jax.lax.dot_general(
+                e, v.astype(jnp.float32), (((2,), (1,)), ((0,), (0,))),
+                precision=jax.lax.Precision.HIGHEST, preferred_element_type=jnp.float32)
+        l = l * corr + jnp.sum(e, axis=-1, keepdims=True)
+        return m_new, l, acc * corr + pv
+
+    init = (
+        jnp.full((nkv, g, 1), NEG_INF, jnp.float32),
+        jnp.zeros((nkv, g, 1), jnp.float32),
+        jnp.zeros((nkv, g, hd), jnp.float32),
+    )
+    _, l, acc = jax.lax.fori_loop(0, n, body, init)
+    nxt[0] = (s0 + n) % 2
+    o_ref[...] = acc / jnp.maximum(l, 1e-30)
 
 
 def _live_page(i, b, j, tbl, npg, ppb):
@@ -230,7 +381,7 @@ def _live_page(i, b, j, tbl, npg, ppb):
     return tbl[b, lp]
 
 
-def _live_decode_kernel(
+def _per_page_kernel(
     tbl_ref,  # (B, P) scalar-prefetch page table
     lay_ref,  # (1,) scalar-prefetch layer of the stack (read by the index maps)
     npg_ref,  # (B,) scalar-prefetch live pages per slot
@@ -244,9 +395,10 @@ def _live_decode_kernel(
     ppb: int,
     n_blocks: int,
 ):
-    """One (slot, block of ``ppb`` pages) grid step over every head. Pages
-    past the slot's last live page are skipped; the online softmax is the
-    ragged kernel's, in f32, on the VPU (one query row per head)."""
+    """One (slot, block of ``ppb`` pages) grid step over every head, each
+    page a pipelined input of its own. Pages past the slot's last live page
+    are skipped; the online softmax is updated per page, in f32, on the VPU
+    (one query row per head)."""
     k_refs, v_refs, pos_refs = refs[:ppb], refs[ppb : 2 * ppb], refs[2 * ppb : 3 * ppb]
     o_ref, acc_ref, m_ref, l_ref = refs[3 * ppb :]
     b = pl.program_id(0)
@@ -295,44 +447,19 @@ def _live_decode_kernel(
         o_ref[...] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
 
 
-def paged_decode_attention(
-    q: jax.Array,  # (B, nq, hd) one query per slot
-    k_pages: jax.Array,  # (L, N, nkv, p, hd) layer-stacked pool
-    v_pages: jax.Array,  # (L, N, nkv, p, hd)
-    pos_pages: jax.Array,  # (L, p, N) int32 absolute positions; -1 = empty
-    table: jax.Array,  # (B, P) int32 per-slot page table
-    layer: jax.Array,  # () int32: which of the L stacked layers to read
-    q_pos: jax.Array,  # (B,) int32 query positions
-    *,
-    causal: bool = True,
-    window: int = 0,
-    scale: Optional[float] = None,
-    pages_per_block: int = 8,
-    interpret: bool = False,
-) -> jax.Array:  # (B, nq, hd) f32
-    """Decode attention read through the page table, live pages only.
-
-    Slot ``b`` reads logical pages ``0 .. q_pos[b] // p`` (its live length)
-    of layer ``layer``; each grid step covers ``pages_per_block`` of them,
-    and the layer index is a scalar-prefetch operand, so the stack is never
-    sliced. Rows are masked by their stored positions as ``attend`` masks
-    them (``pos >= 0``, causal, window); a slot with no valid row returns
-    zeros. Positions past a slot's own never hold valid rows in the decode
-    step (pages map lazily and are scrubbed when mapped), which is what lets
-    the read stop at the live length.
-
-    K/V come as ``(L, N, nkv, p, hd)`` and positions as ``(L, p, N)``: the
-    pool's ``(L, N, p, nkv, hd)`` and ``(L, N, p)`` pages with the in-page
-    axis moved next to the last, which is how the TPU lays them out for
-    ``p = 16`` (tiles over ``(p, hd)``, and over ``(p, N)`` for positions),
-    so taking them so costs no copy of the pool."""
+def _per_page_attention(q, k_pages, v_pages, pos_pages, table, layer, q_pos, *,
+                        causal, window, scale, ppb, interpret):
+    """:func:`paged_decode_attention` with each page a grid-pipelined input:
+    the read for head sizes that are not whole 128-lane tiles or pages of
+    other than a multiple of 8 rows, which Mosaic cannot copy by hand (it
+    slices an array only at tiles), and for pools whose position plane
+    would not fit ``_POS_PLANE_BYTES`` of VMEM (it reads each page's
+    positions beside its K/V)."""
     B, nq, hd = q.shape
     L, N, nkv, p, _ = k_pages.shape
     P = table.shape[1]
     assert nq % nkv == 0
     g = nq // nkv
-    scale = scale if scale is not None else 1.0 / (hd**0.5)
-    ppb = max(1, min(int(pages_per_block), P))
     nb = -(-P // ppb)
     npg = jnp.clip(q_pos.astype(jnp.int32) // p + 1, 1, P)
 
@@ -367,7 +494,7 @@ def paged_decode_attention(
         ],
     )
     kernel = functools.partial(
-        _live_decode_kernel, scale=float(scale), causal=bool(causal),
+        _per_page_kernel, scale=float(scale), causal=bool(causal),
         window=int(window), ppb=ppb, n_blocks=nb,
     )
     # query head h = hk * g + r reads kv head hk (GQA)
@@ -388,3 +515,97 @@ def paged_decode_attention(
         *([pos_pages.astype(jnp.int32)] * ppb),
     )
     return out.transpose(0, 2, 1, 3, 4).reshape(B, nq, hd)
+
+
+def paged_decode_attention(
+    q: jax.Array,  # (B, nq, hd) one query per slot
+    k_pages: jax.Array,  # (L, N, nkv, p, hd) layer-stacked pool
+    v_pages: jax.Array,  # (L, N, nkv, p, hd)
+    pos_pages: jax.Array,  # (L, p, N) int32 absolute positions; -1 = empty
+    table: jax.Array,  # (B, P) int32 per-slot page table
+    layer: jax.Array,  # () int32: which of the L stacked layers to read
+    q_pos: jax.Array,  # (B,) int32 query positions
+    *,
+    causal: bool = True,
+    window: int = 0,
+    scale: Optional[float] = None,
+    pages_per_block: Optional[int] = None,
+    interpret: bool = False,
+) -> jax.Array:  # (B, nq, hd) f32
+    """Decode attention read through the page table, live pages only.
+
+    Slot ``b`` reads logical pages ``0 .. q_pos[b] // p`` (its live length)
+    of layer ``layer``, ``pages_per_block`` of them at a time (by default as
+    many as make 128 keys); the grid runs over slots and a loop over each
+    slot's live blocks, so nothing is issued for a page past that length.
+    K/V stay in HBM and each live page is copied into VMEM by hand; the
+    layer's position plane is fetched once per call. Rows are masked by
+    their stored positions as ``attend`` masks them (``pos >= 0``, causal,
+    window); a slot with no valid row returns zeros. Positions past a
+    slot's own never hold valid rows in the decode step (pages map lazily
+    and are scrubbed when mapped), which is what lets the read stop at the
+    live length. Scores are ``q @ k^T`` on the operands' own dtype with f32
+    accumulation; the weights stay f32 for ``@ v`` (bf16 ``v``: three bf16
+    parts of them, which is exact).
+
+    K/V come as ``(L, N, nkv, p, hd)`` and positions as ``(L, p, N)``: the
+    pool's ``(L, N, p, nkv, hd)`` and ``(L, N, p)`` pages with the in-page
+    axis moved next to the last, which is how the TPU lays them out for
+    ``p = 16`` (tiles over ``(p, hd)``, and over ``(p, N)`` for positions),
+    so taking them so costs no copy of the pool."""
+    B, nq, hd = q.shape
+    L, N, nkv, p, _ = k_pages.shape
+    P = table.shape[1]
+    assert nq % nkv == 0
+    g = nq // nkv
+    scale = scale if scale is not None else 1.0 / (hd**0.5)
+    n_win = -(-N // _POS_LANES)
+    if hd % 128 or p % 8 or p * n_win * _POS_LANES * 4 > _POS_PLANE_BYTES:
+        return _per_page_attention(
+            q, k_pages, v_pages, pos_pages, table, layer, q_pos, causal=causal,
+            window=window, scale=scale, ppb=max(1, min(int(pages_per_block or 8), P)),
+            interpret=interpret)
+    ppb = max(1, min(int(pages_per_block or max(1, _POS_LANES // p)), P))
+    npg = jnp.clip(q_pos.astype(jnp.int32) // p + 1, 1, P)
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    head_spec = pl.BlockSpec((None, nkv, g, hd), lambda b, *_: (b, 0, 0, 0))
+    # one block over-hanging the plane: fetched once, its 128-lane windows
+    # all in bounds
+    pos_spec = pl.BlockSpec(
+        (None, p, n_win * _POS_LANES), lambda b, tbl, lay, n, qp: (lay[0], 0, 0))
+    dt = jnp.promote_types(q.dtype, k_pages.dtype)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,
+        grid=(B,),
+        in_specs=[head_spec, hbm, hbm, pos_spec],
+        out_specs=head_spec,
+        scratch_shapes=[
+            pltpu.VMEM((2, nkv, ppb * p, hd), k_pages.dtype),
+            pltpu.VMEM((2, nkv, ppb * p, hd), v_pages.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SMEM((1,), jnp.int32),
+        ],
+    )
+    kernel = functools.partial(
+        _live_decode_kernel, scale=float(scale), causal=bool(causal),
+        window=int(window), ppb=ppb,
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, nkv, g, hd), jnp.float32),
+        # slots run in order: a slot's last block starts the next one's copies
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+    )(
+        table.astype(jnp.int32),
+        jnp.reshape(layer, (1,)).astype(jnp.int32),
+        npg,
+        q_pos.astype(jnp.int32),
+        # query head h = hk * g + r reads kv head hk (GQA)
+        q.astype(dt).reshape(B, nkv, g, hd),
+        k_pages,
+        v_pages,
+        pos_pages.astype(jnp.int32),
+    )
+    return out.reshape(B, nq, hd)

@@ -164,11 +164,13 @@ def test_paged_pallas_backend_matches_xla():
 # ---------------------------------------------------------------------------
 
 
-def _live_pool(rng, L, N, p, nkv, hd, P, q_pos, free):
+def _live_pool(rng, L, N, p, nkv, hd, P, q_pos, free, shuffle=False, share=None):
     """A layer-stacked pool as the decode step sees it: each live slot maps
     its pages up to its position (plus one scrubbed look-ahead page), rows
     hold their own positions up to the slot's and -1 after, the unmapped
-    tail is NULL; free slots map SCRATCH, whose rows hold garbage."""
+    tail is NULL; free slots map SCRATCH, whose rows hold garbage.
+    ``shuffle`` hands out the physical pages in a random order;
+    ``share=(a, b)`` maps slot b's first page to slot a's (a shared prefix)."""
     k = rng.normal(size=(L, N, p, nkv, hd)).astype(np.float32)
     v = rng.normal(size=(L, N, p, nkv, hd)).astype(np.float32)
     pos = np.full((L, N, p), -1, np.int32)
@@ -176,57 +178,118 @@ def _live_pool(rng, L, N, p, nkv, hd, P, q_pos, free):
     pos[:, SCRATCH_PAGE] = rng.integers(1, 3 * p, size=p)
     pos[:, SCRATCH_PAGE, 0] = 0
     table = np.full((len(q_pos), P), NULL_PAGE, np.int32)
-    nxt = 2
+    ids = np.arange(2, N)
+    if shuffle:
+        rng.shuffle(ids)
+    free_ids = iter(ids)
     for b, qp in enumerate(q_pos):
         if b in free:
             table[b] = SCRATCH_PAGE
             continue
         n = qp // p + 1
         for i in range(min(P, n + 1)):
+            if share is not None and (b, i) == (share[1], 0):
+                table[b, 0] = table[share[0], 0]
+                continue
+            nxt = next(free_ids)
             table[b, i] = nxt
             t = i * p + np.arange(p)
             pos[:, nxt] = np.where(t <= qp, t, -1)
-            nxt += 1
-    assert nxt <= N
     return k, v, pos, table
 
 
+# pool shapes of the live-page oracle test: in-page rows p, head size hd,
+# pages per slot P, dtype of pool and query. "small" heads (hd 8) take the
+# per-page read; heads of 128 the hand-copied blocks, "served" at the decode
+# cells' tiling (16-row pages, bf16, 8 pages = 128 keys a block)
+_LIVE_LAYOUTS = {
+    "small": dict(p=4, hd=8, P=6),
+    "served": dict(p=16, hd=128, P=16, dtype=jnp.bfloat16),
+    "shuffled": dict(p=8, hd=128, P=6, shuffle=True, share=(1, 2)),
+    # a plane too large for VMEM (budget 0): the per-page read at heads of
+    # 128, pages spread over full 128-page windows and the ragged last one
+    "big-plane": dict(p=8, hd=128, P=6, shuffle=True, N=300, plane_bytes=0),
+}
+
+
 @pytest.mark.parametrize(
-    "nq,nkv,causal,window,ppb",
-    [(4, 2, True, 0, 2), (2, 2, True, 0, 4), (4, 2, True, 5, 2), (4, 4, False, 0, 3)],
-    ids=["gqa", "mha-blocks-of-4", "window", "bidirectional-blocks-of-3"],
+    "nq,nkv,causal,window,ppb,layout",
+    [(4, 2, True, 0, 2, "small"), (2, 2, True, 0, 4, "small"), (4, 2, True, 5, 2, "small"),
+     (4, 4, False, 0, 3, "small"), (2, 2, True, 0, None, "served"),
+     (4, 4, False, 0, 4, "shuffled"), (8, 2, True, 0, None, "served"),
+     (4, 2, True, 5, 2, "big-plane")],
+    ids=["gqa", "mha-blocks-of-4", "window", "bidirectional-blocks-of-3",
+         "served-tiling-bf16", "shuffled-shared-prefix", "gqa-4-hd128-bf16",
+         "positions-per-page"],
 )
-def test_live_page_decode_attention_matches_its_xla_oracle(nq, nkv, causal, window, ppb):
+def test_live_page_decode_attention_matches_its_xla_oracle(
+    nq, nkv, causal, window, ppb, layout, monkeypatch
+):
     """The live-page kernel (interpret mode) against the gather + attend
     formulation the step runs off TPU, on lengths at and across page and
     block boundaries, NULL-mapped tails, a full slot and a free slot on
-    SCRATCH, reading layer 1 of a two-layer stack."""
+    SCRATCH, reading layer 1 of a two-layer stack.
+
+    bf16 layouts hold the kernel to the oracle run in float32 on the same
+    bf16 values, at the same 1e-5: the kernel's products of bf16 operands
+    are exact and it accumulates and weighs in f32, whereas ``attend`` in
+    bf16 rounds scores, weights and output to bf16 (2**-9 relative each),
+    which would take a tolerance near 1e-2, loose enough to hide a page
+    read twice or a row masked wrongly."""
     from repro.config import AttentionConfig
-    from repro.kernels.paged import paged_decode_attention
+    from repro.kernels import paged as PG
     from repro.models import paged_kv as PKV
 
+    lay = dict(_LIVE_LAYOUTS[layout])
+    dtype = lay.pop("dtype", jnp.float32)
+    if "plane_bytes" in lay:
+        monkeypatch.setattr(PG, "_POS_PLANE_BYTES", lay.pop("plane_bytes"))
     rng = np.random.default_rng(7)
-    L, p, hd, P = 2, 4, 8, 6
-    q_pos = [0, p - 1, p, ppb * p - 1, ppb * p + 1, P * p - 1, 0]
+    L, p, hd, P = 2, lay["p"], lay["hd"], lay["P"]
+    bp = (ppb or 128 // p) * p  # keys a block
+    q_pos = ([0, p - 1, p, bp - 1, bp + 1, P * p - 1, 0] if ppb else
+             [0, p - 1, bp - 1, bp, P * p - 1, 0])
     free = {len(q_pos) - 1}
-    N = 2 + sum(min(P, qp // p + 2) for b, qp in enumerate(q_pos) if b not in free)
-    k, v, pos, table = _live_pool(rng, L, N, p, nkv, hd, P, q_pos, free)
-    q = jnp.asarray(rng.normal(size=(len(q_pos), nq, hd)), jnp.float32)
+    N = lay.get("N") or 2 + sum(
+        min(P, qp // p + 2) for b, qp in enumerate(q_pos) if b not in free)
+    k, v, pos, table = _live_pool(rng, L, N, p, nkv, hd, P, q_pos, free,
+                                  shuffle=lay.get("shuffle", False), share=lay.get("share"))
+    if "share" in lay:
+        assert table[2, 0] == table[1, 0]
+    q = jnp.asarray(rng.normal(size=(len(q_pos), nq, hd)), dtype)
     qp = jnp.asarray(q_pos, jnp.int32)
     layer = jnp.int32(1)
-    stacks = [PKV.to_leaf(jnp.asarray(a), 1) for a in (k, v, pos)]
+    stacks = [PKV.to_leaf(jnp.asarray(a, a.dtype if a.dtype == np.int32 else dtype), 1)
+              for a in (k, v, pos)]
     cfg = tiny_cfg(attn=AttentionConfig(
         n_heads=nq, n_kv_heads=nkv, head_dim=hd, causal=causal, window=window))
-    cache = {n: PKV.PagedLeaf(a, jnp.asarray(table), layer)
+    # the oracle in float32 on the operands' own values
+    cache = {n: PKV.PagedLeaf(a.astype(jnp.float32) if n != "pos" else a,
+                              jnp.asarray(table), layer)
              for n, a in zip(("k", "v", "pos"), stacks)}
-    want = PKV.attend_paged(q[:, None], cache, qp[:, None], cfg)
-    got = paged_decode_attention(
+    want = PKV.attend_paged(q[:, None].astype(jnp.float32), cache, qp[:, None], cfg)
+    got = PG.paged_decode_attention(
         q, *stacks, jnp.asarray(table), layer, qp, causal=causal, window=window,
         pages_per_block=ppb, interpret=True,
     )
     np.testing.assert_allclose(
         np.asarray(got).reshape(want.shape), np.asarray(want), rtol=1e-5, atol=1e-5
     )
+
+
+def test_split_weights_sum_back_to_their_f32_values():
+    """The live-page kernel weighs bf16 values with three bf16 parts of each
+    f32 softmax weight: their sum must give the weight back to f32 rounding,
+    or ``@ v`` would round the weights below f32."""
+    from repro.kernels.paged import _split3
+
+    rng = np.random.default_rng(3)
+    e = np.concatenate([rng.random(4096), rng.random(256) * 1e-20, [0.0, 1.0]])
+    e = jnp.asarray(e, jnp.float32)[None, None]
+    parts = np.asarray(_split3(e).astype(jnp.float32))  # (1, 3, n)
+    assert parts.shape == (1, 3, e.shape[-1])
+    back = (parts[:, 0] + parts[:, 1]) + parts[:, 2]
+    np.testing.assert_allclose(back, np.asarray(e)[:, 0], rtol=2.0**-24, atol=0)
 
 
 def _decode_engine(B=3, **kw):

@@ -17,6 +17,7 @@ import os
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -133,32 +134,64 @@ def test_ragged_attention(one_chip, quant):
     _compile(one_chip, attn, *shapes)
 
 
-def test_paged_decode_attention(one_chip):
-    """The padded decode step's live-page read at the decode cell's size:
-    16 slots, ctx 2048 in 16-token pages, 12 stacked layers, pool in the
-    layout the step hands it (in-page axis next to the last)."""
+@pytest.mark.parametrize("layers,n_pages", [(GROUPS, 2050), (24, 1538)], ids=["mod", "dense"])
+def test_paged_decode_attention(one_chip, layers, n_pages):
+    """The padded decode step's live-page read at the decode cells' sizes:
+    16 slots, ctx 2048 in 16-token pages, the stacked layers and pool of
+    ``mod-paper-1b`` (12 full layers, 2,050 pages) and of its dense twin (24,
+    1,538), in the layout the step hands it (in-page axis next to the last)."""
     slots, P = 16, 2048 // PAGE
-    N = slots * P + 2
+    kv = ((layers, n_pages, H, PAGE, HD), BF16)
     _compile(one_chip, lambda *a: pg.paged_decode_attention(*a),
-             ((slots, H, HD), BF16), ((GROUPS, N, H, PAGE, HD), BF16),
-             ((GROUPS, N, H, PAGE, HD), BF16), ((GROUPS, PAGE, N), I32),
+             ((slots, H, HD), BF16), kv, kv, ((layers, PAGE, n_pages), I32),
              ((slots, P), I32), ((), I32), ((slots,), I32))
 
 
-def test_paged_decode_step_reads_the_pool_in_place(one_chip):
+@pytest.mark.parametrize("page", [4, 8])
+def test_paged_decode_attention_small_pages(one_chip, page):
+    """Heads of 128 in bf16 pages of 4 rows, which Mosaic cannot slice into
+    the hand-copied blocks (they take the per-page read), and of 8."""
+    slots, P, N = 4, 32, 130
+    kv = ((2, N, 4, page, HD), BF16)
+    _compile(one_chip, lambda *a: pg.paged_decode_attention(*a),
+             ((slots, 8, HD), BF16), kv, kv, ((2, page, N), I32),
+             ((slots, P), I32), ((), I32), ((slots,), I32))
+
+
+# the tiny model, and one laid out as the decode cells are: 14 heads of 128
+# in bf16, 16-row pages, ctx 2048 (a pool too large to sit in VMEM)
+_STEP_LAYOUTS = {
+    "tiny": dict(cfg={}, ctx=32, page=4),
+    "served": dict(cfg=dict(dtype="bfloat16", attn=dict(n_heads=H, n_kv_heads=H, head_dim=HD)),
+                   ctx=2048, page=PAGE),
+}
+
+
+@pytest.mark.parametrize("layout", list(_STEP_LAYOUTS))
+def test_paged_decode_step_reads_the_pool_in_place(one_chip, layout):
     """The engine's padded paged decode step compiled for the chip: the
     live-page kernel reads the pool through the table, and no full ring
     ``(B, ctx, nkv, hd)`` nor stacked ``(G, B, ctx, ...)`` K/V buffer is
-    built."""
+    built. In the served layout the pool's pages are already laid out as
+    the kernel takes them, so nothing of the pool's size is copied or
+    transposed either (the tiny model's 2 heads of 16 in 4-row pages are
+    tiled otherwise, and XLA relayouts them with any kernel)."""
+    import re
+
+    from repro.config import AttentionConfig
     from repro.models import api
     from repro.serve import EngineConfig, ServingEngine
     from tests.helpers import tiny_cfg
 
-    cfg = tiny_cfg()
-    slots, ctx = 4, 32
+    spec = _STEP_LAYOUTS[layout]
+    kw = dict(spec["cfg"])
+    if "attn" in kw:
+        kw["attn"] = AttentionConfig(**kw["attn"])
+    cfg = tiny_cfg(**kw)
+    slots, ctx = 4, spec["ctx"]
     eng = ServingEngine(api.init_model(jax.random.PRNGKey(0), cfg), cfg,
-                        engine=EngineConfig(batch_size=slots, ctx=ctx, page_size=4,
-                                            prefill_chunk=4))
+                        engine=EngineConfig(batch_size=slots, ctx=ctx, page_size=spec["page"],
+                                            prefill_chunk=spec["page"]))
     pool = eng.pool
     args = (eng.params, pool.pages, pool.scales, pool.resid, pool.device_table(),
             jnp.zeros((slots, 1), I32), jnp.zeros((slots,), I32), jnp.ones((slots,), bool))
@@ -169,6 +202,15 @@ def test_paged_decode_step_reads_the_pool_in_place(one_chip):
     nkv, hd, G = cfg.attn.n_kv_heads, cfg.head_dim, cfg.n_layers // 2
     assert f"[{slots},{ctx},{nkv},{hd}]" not in text
     assert f"[{G},{slots},{ctx}," not in text
+    if layout == "served":
+        kv_size = max(int(np.prod(a.shape)) for a in jax.tree.leaves(pool.pages))
+        moved = []
+        for line in text.splitlines():
+            op = re.search(r" (copy|copy-start|copy-done|transpose)\(", line)
+            dims = re.findall(r"\[([0-9,]+)\]", line[: op.start()] if op else "")
+            if any(np.prod([int(d) for d in ds.split(",")]) == kv_size for ds in dims):
+                moved.append(line)
+        assert not moved, moved[:2]
 
 
 def test_fused_dispatch_refuses_to_compile(one_chip):
